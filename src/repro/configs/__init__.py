@@ -29,6 +29,17 @@ def get_config(arch: str) -> ModelConfig:
     return importlib.import_module(_ARCH_MODULES[arch]).CONFIG
 
 
+def get_chip_share(arch: str) -> ModelConfig:
+    """The share of ``arch`` one chip holds in a stated deployment (the
+    config module's ``CHIP_SHARE``; its docstring states the cut)."""
+    get_config(arch)    # validates the name
+    share = getattr(importlib.import_module(_ARCH_MODULES[arch]),
+                    "CHIP_SHARE", None)
+    if share is None:
+        raise KeyError(f"{arch!r} has no one-chip share configured")
+    return share
+
+
 def supports_long_context(cfg: ModelConfig) -> bool:
     """Whether the arch runs long_500k *natively* (sub-quadratic without a
     variant toggle).  Others get the explicit SWA variant (DESIGN.md §5)."""

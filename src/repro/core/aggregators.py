@@ -274,16 +274,22 @@ def _apply_grouping(stacked, grouping: Grouping):
         # identity, but lowers as avoidable data movement on sharded grads.
         return stacked
     if not grouping.is_even:
-        from repro.core.grouping import assignment_matrix
-        s = jnp.asarray(assignment_matrix(grouping))
-        sizes = jnp.asarray(grouping.batch_sizes, jnp.float32)
+        batches = grouping.batches()
+        sizes = grouping.batch_sizes
 
         def leaf_uneven(g):
-            # contraction over the worker axis only — no reshape(m, -1), so
-            # a sharded trailing dim stays sharded (coordinate-local).
-            sums = jnp.einsum("km,m...->k...", s, g.astype(jnp.float32))
-            means = sums / sizes.reshape((k,) + (1,) * (g.ndim - 1))
-            return means.astype(g.dtype)
+            # members summed one by one in a fixed order: elementwise over
+            # the worker axis only, so a sharded trailing dim stays sharded
+            # and every shard adds in the same order (a dot over m may
+            # block its sum differently for different slice shapes).
+            g32 = g.astype(jnp.float32)
+            rows = []
+            for members, size in zip(batches, sizes):
+                acc = g32[members[0]]
+                for w in members[1:]:
+                    acc = acc + g32[w]
+                rows.append(acc / size)
+            return jnp.stack(rows).astype(g.dtype)
 
         return jax.tree.map(leaf_uneven, stacked)
 

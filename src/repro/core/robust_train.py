@@ -404,7 +404,6 @@ def make_shardmap_aggregate(cfg: RobustConfig, mesh, worker_axes=("data",)):
     grouping.  Returns ``fn(stacked_local_grads) -> agg_grad`` to be called
     inside shard_map (worker axis unstacked: each rank passes its own grad).
     """
-    from jax.experimental.shard_map import shard_map  # noqa: F401
     k = cfg.resolved_num_batches()
     m = cfg.num_workers
     if m % k != 0:

@@ -16,39 +16,40 @@ This module fuses the whole thing into ONE kernel invocation:
     dense membership matrix (``core.grouping.assignment_matrix``), so any
     grouping scheme — contiguous / strided / seeded, even or uneven batch
     sizes — is the same MXU contraction;
-  * the (k, d) batch-mean block Z is accumulated into a VMEM-resident
-    buffer, and the trim weights (paper Remark 2) AND the full Weiszfeld
-    fixed-point loop run on that buffer without touching HBM again; only
-    the final aggregate y (d,) is written back.
+  * the (k, d) batch-mean block Z is accumulated into a VMEM scratch, and
+    the trim weights (paper Remark 2) AND the full Weiszfeld fixed-point
+    loop run on that buffer without touching HBM again; only the final
+    aggregate y (d,) is written back.
 
-VMEM budget: the resident set is Z (k, d_pad) + y (d_pad) + one G tile
-(m, TILE_D) + S (k, m), all f32.  With k <= 64 this supports d up to
-~10^5 per call inside the default 8 MiB cap (``VMEM_BUDGET_BYTES``); the
-production dispatcher (``core.aggregators.gmom_aggregator``) falls back to
-the unfused jnp path above that, so model-scale leaves keep working.
+VMEM budget (``round_resident_bytes``): Z with k padded to whole sublane
+tiles, the iterate output (counted as 8 sublanes and double-buffered), and
+the double-buffered input tiles, all f32.  At m=50, k=11 that admits d up
+to 96256 inside ``VMEM_BUDGET_BYTES``; the production dispatcher
+(``core.aggregators.gmom_aggregator``) falls back to the unfused jnp path
+above that, so model-scale leaves keep working.  tests/test_tpu_compile.py
+compiles the kernel for a described v5e at the largest admitted d.
 
 ``round_aggregate_ref`` is the pure-jnp twin that mirrors the kernel's tile
 loop and operation order exactly — it is bit-identical to the kernel in
-interpret mode (tests/test_round_kernel.py asserts exact equality) and is
-the fused formulation benchmarked on non-TPU backends.
+interpret mode (tests/test_round_kernel.py asserts exact equality).
 
 ``linreg_round_*`` goes one stage further for the paper's linear-regression
 substrate (§4): the kernel receives the RAW worker batches (X, y) and the
 current iterate theta, computes every worker's full-batch gradient
 (1/n) X_j^T (X_j theta - y_j) in-kernel (two streamed passes over X), and
 feeds it straight into the same means+trim+Weiszfeld tail — the entire
-round of Algorithm 2 as one kernel.
+round of Algorithm 2 as one kernel.  The v5e compiler refuses it (see its
+section below).
 
 The Weiszfeld loop is an early-exiting ``lax.while_loop`` with the same
 stopping rule as the unfused jnp path (squared movement <= tol^2, capped at
-``max_iters``).  In-kernel the loop carries ONLY scalars — the iterate
-lives in the output ref (``_finish_round``) — which is the
-Mosaic-friendliest shape for a data-dependent loop; the jnp reference
-(``_weiszfeld_resident``) carries the iterate through an ordinary array
-while-carry but computes the identical values iteration for iteration,
-which is what makes the kernel/reference pair bit-identical in interpret
-mode.  Validating the while-with-ref-state lowering on real TPU hardware
-is a recorded ROADMAP follow-up.
+``max_iters``).  Every pass walks the resident block one (k, TILE_D) chunk
+at a time (``_trim_weiszfeld``), so no vector value larger than a chunk is
+live and the compiled code does not grow with d.  In-kernel the loops carry
+only scalars and (k, 1) columns — the iterate lives in the output ref; the
+jnp twin carries the iterate as an ordinary array but computes the
+identical values iteration for iteration, which is what makes the pair
+bit-identical in interpret mode.
 """
 
 from __future__ import annotations
@@ -59,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.grouping import Grouping, assignment_matrix
 
@@ -67,13 +69,14 @@ from repro.core.grouping import Grouping, assignment_matrix
 # no jnp.sum/jnp.mean over the member axis outside it (repro.verify RV101).
 
 TILE_D = 512
-# The declared per-core VMEM capacity the budget is provisioned against
-# (TPU v4/v5 class cores carry ~16 MiB).  repro.verify's static VMEM audit
-# (RV204) checks VMEM_BUDGET_BYTES <= DEVICE_VMEM_BYTES and that the
-# dispatcher's fits_vmem() and the kernel's own _check_vmem() guard agree
-# on a shape grid, so the two formulas cannot drift apart silently.
+# The scoped-VMEM limit the TPU compiler enforces on a kernel by default on
+# v5e (its refusals quote "limit 16.00M"); compiling past it raises
+# RESOURCE_EXHAUSTED.  The budget keeps a quarter of it for the compiler's
+# own scratch.  repro.verify's static VMEM audit (RV204) checks
+# VMEM_BUDGET_BYTES <= DEVICE_VMEM_BYTES and that the dispatcher's
+# fits_vmem() and the kernel's own _check_vmem() guard agree on a shape grid.
 DEVICE_VMEM_BYTES = 16 * 2**20
-VMEM_BUDGET_BYTES = 8 * 2**20   # conservative half of DEVICE_VMEM_BYTES
+VMEM_BUDGET_BYTES = 12 * 2**20
 
 
 def default_use_pallas(target_backend: str | None = None) -> bool:
@@ -97,19 +100,28 @@ def _pad_axis(x, tile: int, axis: int):
 # ---------------------------------------------------------------------------
 # building blocks shared verbatim by the kernel and its jnp reference —
 # sharing the exact op sequence is what buys bit-equality in interpret mode.
+#
+# The resident batch-mean block is held as (n_chunks, k, chunk): chunk c is
+# columns [c*chunk, (c+1)*chunk) of the (k, d_pad) block, so every loop
+# indexes the untiled leading axis and no value larger than (k, chunk) is
+# ever live.  Per-batch quantities are (k, 1) columns: Mosaic cannot
+# relayout a (k,) vector into a row or column.
 
 def _median_small(x):
-    """``jnp.median`` of a small 1D vector without sorting.
+    """``jnp.median`` of a small (k, 1) column without sorting.
 
     Mosaic has no in-kernel sort; for the k <= 64 trim-weight median we rank
     every element against every other (O(k^2) compares on the VPU, ties
     broken by index so ranks are a permutation) and select the middle order
-    statistic(s) by mask."""
+    statistic(s) by mask.  The row copy of ``x`` is built by masking and
+    adding rows, not by a transpose.  Every sum here has exactly one
+    nonzero term, so it is exact."""
     k = x.shape[0]
     ii = jax.lax.broadcasted_iota(jnp.int32, (k, k), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (k, k), 1)
-    xi, xj = x[:, None], x[None, :]
-    rank = jnp.sum((xj < xi) | ((xj == xi) & (jj < ii)), axis=1)   # (k,)
+    row = _add_rows(jnp.where(ii == jj, x, jnp.zeros((k, k), x.dtype)))
+    less = (row < x) | ((row == x) & (jj < ii))
+    rank = jnp.sum(less.astype(jnp.int32), axis=1, keepdims=True)   # (k, 1)
 
     def order_stat(r):
         return jnp.sum(jnp.where(rank == r, x, jnp.zeros_like(x)))
@@ -119,114 +131,164 @@ def _median_small(x):
     return 0.5 * (order_stat(k // 2 - 1) + order_stat(k // 2))
 
 
-def _trim_weights_resident(z, *, trim_multiplier, k):
-    """Paper Remark-2 trim weights from the VMEM-resident batch means."""
-    if trim_multiplier is None:
-        return jnp.ones((k,), jnp.float32)
-    norms = jnp.sqrt(jnp.sum(z * z, axis=1))
+def _trim_weights(sq_norms, trim_multiplier):
+    """Paper Remark-2 trim weights, a (k, 1) column, from the squared norms
+    of the batch means."""
+    norms = jnp.sqrt(sq_norms)
     tau = trim_multiplier * _median_small(norms) + 1e-12
     w = (norms <= tau).astype(jnp.float32)
     return jnp.where(jnp.sum(w) > 0, w, jnp.ones_like(w))
 
 
-def _weiszfeld_init(z, w, eps):
-    """Weighted-mean initial iterate (the k=1 aggregate), shape (1, d)."""
-    w_sum = jnp.maximum(jnp.sum(w), eps)
-    return jnp.dot(w.reshape(1, z.shape[0]), z,
-                   preferred_element_type=jnp.float32) / w_sum
+def _row_sq(x):
+    """Per-row sum of squares of a (k, chunk) slab: (k, 1)."""
+    return jnp.sum(x * x, axis=1, keepdims=True)
 
 
-def _weiszfeld_step_vals(z, w, y, *, eps):
-    """One Weiszfeld update on the resident block: (y_new, squared move)."""
-    diff = z - y                                   # (k, d)
-    sq = jnp.sum(diff * diff, axis=1)              # (k,)
-    dist = jnp.sqrt(sq + eps * eps)
-    inv = w / dist
-    denom = jnp.maximum(jnp.sum(inv), eps)
-    y_new = jnp.dot((inv / denom).reshape(1, z.shape[0]), z,
-                    preferred_element_type=jnp.float32)
-    return y_new, jnp.sum((y_new - y) ** 2)
+def _add_rows(x):
+    """Sum of the rows of a (k, n) value as an unrolled add chain: (1, n).
+    The fixed order keeps the kernel and its jnp twin bit-identical (a
+    reduction over the member axis may be reassociated per fusion)."""
+    acc = x[0:1]
+    for l in range(1, x.shape[0]):
+        acc = acc + x[l:l + 1]
+    return acc
 
 
-def _weiszfeld_resident(z, w, *, max_iters, tol, eps):
-    """Full Weiszfeld loop on a resident (k, d) block -> (1, d) median.
+def _weighted_rows(c, x):
+    """sum_l c[l] * x[l] for a (k, 1) column c: (1, chunk), on the VPU."""
+    return _add_rows(c * x)
 
-    Early-exiting loop: stop when the squared movement drops to tol^2 or
+
+def _trim_weiszfeld(z_at, y_get, y_set, y, *, k, n_chunks, trim_multiplier,
+                    max_iters, tol, eps):
+    """Remark-2 trim + Weiszfeld on the resident block, chunk by chunk.
+
+    ``z_at(c)`` reads chunk c of the batch means, (k, chunk).  The iterate
+    is reached through ``y_get(y, c)`` -> (1, chunk) and ``y_set(y, c, v)``
+    -> y: in the kernel ``y`` is ``()`` and the iterate lives in the output
+    ref, so every loop carries only scalars and (k, 1) columns; in the jnp
+    twin ``y`` is an ordinary (n_chunks, 1, chunk) array.  Both run the same
+    ops in the same order.  Each iteration makes two passes over the block:
+    distances to the old iterate, then the new iterate and its squared
+    movement.  Early exit: stop when the squared movement drops to tol^2 or
     after ``max_iters`` steps — the same stopping rule as the unfused jnp
-    path.  The kernels inline the identical step with the iterate held in
-    the output ref and only scalars in the while carry (``_finish_round``),
-    so both forms compute the same values iteration for iteration."""
+    path."""
+    fori = jax.lax.fori_loop
+    zero_col = jnp.zeros((k, 1), jnp.float32)
+    if trim_multiplier is None:
+        w = jnp.ones((k, 1), jnp.float32)
+    else:
+        sq = fori(0, n_chunks, lambda c, acc: acc + _row_sq(z_at(c)),
+                  zero_col)
+        w = _trim_weights(sq, trim_multiplier)
+    w_sum = jnp.maximum(jnp.sum(w), eps)
+    y = fori(0, n_chunks,
+             lambda c, y: y_set(y, c, _weighted_rows(w, z_at(c)) / w_sum), y)
+
     def cond(carry):
         _, it, delta2 = carry
         return jnp.logical_and(it < max_iters, delta2 > tol * tol)
 
     def body(carry):
         y, it, _ = carry
-        y_new, delta2 = _weiszfeld_step_vals(z, w, y, eps=eps)
-        return y_new, it + 1, delta2
+        sq = fori(0, n_chunks,
+                  lambda c, acc: acc + _row_sq(z_at(c) - y_get(y, c)),
+                  zero_col)
+        inv = w / jnp.sqrt(sq + eps * eps)
+        coef = inv / jnp.maximum(jnp.sum(inv), eps)
+
+        def update(c, carry):
+            y, delta2 = carry
+            new = _weighted_rows(coef, z_at(c))
+            delta2 = delta2 + jnp.sum((new - y_get(y, c)) ** 2)
+            return y_set(y, c, new), delta2
+
+        y, delta2 = fori(0, n_chunks, update,
+                         (y, jnp.zeros((), jnp.float32)))
+        return y, it + 1, delta2
 
     y, _, _ = jax.lax.while_loop(
-        cond, body, (_weiszfeld_init(z, w, eps),
-                     jnp.zeros((), jnp.int32),
+        cond, body, (y, jnp.zeros((), jnp.int32),
                      jnp.array(jnp.inf, jnp.float32)))
     return y
 
 
-def _means_trim_weiszfeld(z, *, k, trim_multiplier, max_iters, tol, eps):
-    w = _trim_weights_resident(z, trim_multiplier=trim_multiplier, k=k)
-    return _weiszfeld_resident(z, w, max_iters=max_iters, tol=tol, eps=eps)
+def _finish_in_kernel(z_ref, y_ref, **kw):
+    """Kernel tail: trim + Weiszfeld with the iterate in the (n_chunks, 1,
+    chunk) output ref."""
+    def y_set(_, c, v):
+        y_ref[c] = v
+        return ()
+
+    n_chunks = z_ref.shape[0]
+    _trim_weiszfeld(lambda c: z_ref[c], lambda _, c: y_ref[c], y_set, (),
+                    k=z_ref.shape[1], n_chunks=n_chunks, **kw)
 
 
-def _finish_round(z, y_ref, *, trim_multiplier, max_iters, tol, eps):
-    """In-kernel tail: trim + Weiszfeld with the iterate living in the
-    output ref.  The while carry holds only scalars (iteration count and
-    last squared movement) — the Mosaic-friendly loop shape — while every
-    per-iteration value matches ``_weiszfeld_resident`` exactly."""
-    k = z.shape[0]
-    w = _trim_weights_resident(z, trim_multiplier=trim_multiplier, k=k)
-    y_ref[...] = _weiszfeld_init(z, w, eps)
+def _finish_jnp(z, **kw):
+    """jnp twin of ``_finish_in_kernel`` on a (n_chunks, k, chunk) array;
+    returns the (d_pad,) aggregate."""
+    n_chunks, k, chunk = z.shape
+    idx = jax.lax.dynamic_index_in_dim
+    y = _trim_weiszfeld(
+        lambda c: idx(z, c, keepdims=False),
+        lambda y, c: idx(y, c, keepdims=False),
+        lambda y, c, v: jax.lax.dynamic_update_index_in_dim(y, v, c, 0),
+        jnp.zeros((n_chunks, 1, chunk), jnp.float32),
+        k=k, n_chunks=n_chunks, **kw)
+    return y.reshape(-1)
 
-    def cond(carry):
-        it, delta2 = carry
-        return jnp.logical_and(it < max_iters, delta2 > tol * tol)
 
-    def body(carry):
-        it, _ = carry
-        y_new, delta2 = _weiszfeld_step_vals(z, w, y_ref[...], eps=eps)
-        y_ref[...] = y_new
-        return it + 1, delta2
-
-    jax.lax.while_loop(cond, body, (jnp.zeros((), jnp.int32),
-                                    jnp.array(jnp.inf, jnp.float32)))
+def _chunked(z, chunk: int):
+    """(k, d) -> (n_chunks, k, chunk), zero-padding d to a chunk multiple."""
+    z = _pad_axis(z, chunk, 1)
+    k, d_pad = z.shape
+    return z.reshape(k, d_pad // chunk, chunk).transpose(1, 0, 2)
 
 
 # ---------------------------------------------------------------------------
 # kernel 1: stacked gradients -> aggregate   (the scan trainer's hot path)
 
-def _round_kernel(g_ref, s_ref, bsz_ref, y_ref, z_ref, *, n_tiles, tile_d,
-                  trim_multiplier, max_iters, tol, eps):
-    """Grid over d-tiles; z_ref is the VMEM-resident (k, d_pad) accumulator
-    (an output revisited by every step so it persists across the grid)."""
+def _round_kernel(g_ref, s_ref, bsz_ref, y_ref, z_ref, *, n_tiles, **kw):
+    """Grid over d-tiles; z_ref is the VMEM scratch (n_tiles, k, tile_d) that
+    accumulates the batch means, one tile per grid step."""
     i = pl.program_id(0)
     sums = jnp.dot(s_ref[...], g_ref[...],
                    preferred_element_type=jnp.float32)      # (k, tile_d)
-    z_ref[:, pl.ds(i * tile_d, tile_d)] = sums / bsz_ref[...]
+    z_ref[i] = sums / bsz_ref[...]
 
     @pl.when(i == n_tiles - 1)
     def _finish():
-        _finish_round(z_ref[...], y_ref, trim_multiplier=trim_multiplier,
-                      max_iters=max_iters, tol=tol, eps=eps)
+        _finish_in_kernel(z_ref, y_ref, **kw)
+
+
+def _resident_bytes(k: int, d_pad: int, extra_bytes: int) -> int:
+    """VMEM the fused kernels hold beyond their streamed tiles: the batch
+    means with k padded to whole (8, 128) sublane tiles, plus the iterate
+    output (n_chunks, 1, chunk), whose every chunk also fills a whole
+    (8, chunk) tile and which Pallas double-buffers."""
+    k_pad = -(-k // 8) * 8
+    return (k_pad + 2 * 8) * d_pad * 4 + extra_bytes
+
+
+def _tile_bytes(m: int, k: int, tile_d: int) -> int:
+    """The double-buffered input blocks of ``round_aggregate_kernel``: one
+    (m, tile_d) gradient tile, the (k, m) membership matrix and the (k, 1)
+    batch sizes, each padded to (8, 128) tiles."""
+    def padded(rows, cols):
+        return (-(-rows // 8) * 8) * (-(-cols // 128) * 128) * 4
+    return 2 * (padded(m, tile_d) + padded(k, m) + padded(k, 1))
 
 
 def round_resident_bytes(m: int, k: int, d: int,
                          tile_d: int = TILE_D) -> int:
-    """VMEM-resident f32 footprint of ``round_aggregate_kernel``: the Z
-    block + y output + one streamed G tile + the membership matrix.  The
-    dispatcher (``core.aggregators.resolve_round_backend``) and the kernel's
-    own guard use this same formula, so 'auto' never dispatches a shape the
-    kernel would reject."""
+    """VMEM footprint of ``round_aggregate_kernel``.  The dispatcher
+    (``core.aggregators.resolve_round_backend``) and the kernel's own guard
+    use this same formula, so 'auto' never dispatches a shape the kernel
+    would reject."""
     d_pad = -(-d // tile_d) * tile_d
-    return ((k + 1) * d_pad + m * tile_d + k * m) * 4
+    return _resident_bytes(k, d_pad, _tile_bytes(m, k, tile_d))
 
 
 def fits_vmem(m: int, k: int, d: int, tile_d: int = TILE_D) -> bool:
@@ -234,7 +296,7 @@ def fits_vmem(m: int, k: int, d: int, tile_d: int = TILE_D) -> bool:
 
 
 def _check_vmem(k: int, d_pad: int, extra_bytes: int = 0):
-    resident = (k + 1) * d_pad * 4 + extra_bytes
+    resident = _resident_bytes(k, d_pad, extra_bytes)
     if resident > VMEM_BUDGET_BYTES:
         raise ValueError(
             f"fused round kernel resident set {resident} B (k={k}, "
@@ -261,12 +323,12 @@ def round_aggregate_kernel(stacked_grads, grouping: Grouping, *,
     g = _pad_axis(stacked_grads.astype(jnp.float32), tile_d, 1)
     d_pad = g.shape[1]
     n_tiles = d_pad // tile_d
-    _check_vmem(k, d_pad, extra_bytes=(m * tile_d + k * m) * 4)
+    _check_vmem(k, d_pad, extra_bytes=_tile_bytes(m, k, tile_d))
     s = jnp.asarray(assignment_matrix(grouping))
     bsz = jnp.asarray(grouping.batch_sizes, jnp.float32).reshape(k, 1)
 
-    y, _ = pl.pallas_call(
-        functools.partial(_round_kernel, n_tiles=n_tiles, tile_d=tile_d,
+    y = pl.pallas_call(
+        functools.partial(_round_kernel, n_tiles=n_tiles,
                           trim_multiplier=trim_multiplier,
                           max_iters=max_iters, tol=tol, eps=eps),
         grid=(n_tiles,),
@@ -275,17 +337,14 @@ def round_aggregate_kernel(stacked_grads, grouping: Grouping, *,
             pl.BlockSpec((k, m), lambda i: (0, 0)),
             pl.BlockSpec((k, 1), lambda i: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, d_pad), lambda i: (0, 0)),
-            pl.BlockSpec((k, d_pad), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
-            jax.ShapeDtypeStruct((k, d_pad), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((n_tiles, 1, tile_d), lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, 1, tile_d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((n_tiles, k, tile_d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(g, s, bsz)
-    return y[0, :d]
+    return y.reshape(-1)[:d]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -296,27 +355,24 @@ def round_aggregate_ref(stacked_grads, grouping: Grouping, *,
                         eps: float = 1e-12, tile_d: int = TILE_D):
     """jnp twin of ``round_aggregate_kernel``: same ops, same reductions.
 
-    This is the fused formulation on non-TPU backends (one membership
-    matmul for the means, early-exiting flat-block Weiszfeld) and the
-    bit-exact oracle for the kernel in interpret mode.  The
-    means are ONE flat dot rather than a d-tile loop: the contraction runs
-    over the worker axis only, so every output column depends on exactly
-    one input column and the d-tiling of the kernel cannot change any
-    reduction order (tests/test_round_kernel.py asserts exact equality).
-    Only the small (k, d) mean block is padded — the kernel's padded-G
-    tiles produce exactly-zero padded mean columns, so padding Z after the
-    matmul is bitwise the same and skips an O(m d) copy.
+    The bit-exact oracle for the kernel in interpret mode.  The means are
+    one (k, m) x (m, tile_d) dot per d-tile, as in the kernel's grid: a
+    single flat dot may block its sum over the workers differently, which
+    costs the last bit (tests/test_round_kernel.py asserts exact equality).
     """
     m, d = stacked_grads.shape
     k = grouping.num_batches
-    g = stacked_grads.astype(jnp.float32)
+    g = _pad_axis(stacked_grads.astype(jnp.float32), tile_d, 1)
+    n_tiles = g.shape[1] // tile_d
     s = jnp.asarray(assignment_matrix(grouping))
     bsz = jnp.asarray(grouping.batch_sizes, jnp.float32).reshape(k, 1)
-    z = jnp.dot(s, g, preferred_element_type=jnp.float32) / bsz
-    z = _pad_axis(z, tile_d, 1)
-    y = _means_trim_weiszfeld(z, k=k, trim_multiplier=trim_multiplier,
-                              max_iters=max_iters, tol=tol, eps=eps)
-    return y[0, :d]
+    tiles = g.reshape(m, n_tiles, tile_d).transpose(1, 0, 2)
+    z = jax.lax.map(
+        lambda gt: jnp.dot(s, gt, preferred_element_type=jnp.float32) / bsz,
+        tiles)                                          # (n_tiles, k, tile_d)
+    y = _finish_jnp(z, trim_multiplier=trim_multiplier, max_iters=max_iters,
+                    tol=tol, eps=eps)
+    return y[:d]
 
 
 def round_aggregate_pytree(stacked_grads, grouping: Grouping, *,
@@ -355,10 +411,14 @@ def round_aggregate_pytree(stacked_grads, grouping: Grouping, *,
 
 # ---------------------------------------------------------------------------
 # kernel 2: raw linreg batches -> aggregate  (the whole round in-kernel)
+#
+# The v5e compiler refuses this kernel: its batched (m, n) x (m, n, tile_d)
+# dot_general has no TPU dot-dimension encoding.  Nothing on the production
+# path calls it (ROADMAP, Queue 3); its interpret-mode test keeps the tail it
+# shares with kernel 1 honest.
 
 def _linreg_round_kernel(x_ref, t_ref, theta_ref, s_ref, bsz_ref,
-                         y_ref, r_ref, z_ref, *, n_tiles, tile_d, inv_n,
-                         trim_multiplier, max_iters, tol, eps):
+                         y_ref, r_ref, z_ref, *, n_tiles, inv_n, **kw):
     """Grid (2, n_tiles).  Phase 0 streams X to build the residual
     R = X @ theta - y (resident, (m, n)); phase 1 streams X again to form
     each worker's gradient tile (1/n) X^T R, contracts it with the
@@ -369,6 +429,7 @@ def _linreg_round_kernel(x_ref, t_ref, theta_ref, s_ref, bsz_ref,
     i = pl.program_id(1)
     x = x_ref[...]                                     # (m, n, tile_d)
     theta_t = theta_ref[...]                           # (1, tile_d)
+    tile_d = x.shape[2]
 
     @pl.when(phase == 0)
     def _residual():
@@ -391,12 +452,11 @@ def _linreg_round_kernel(x_ref, t_ref, theta_ref, s_ref, bsz_ref,
             preferred_element_type=jnp.float32) * inv_n  # (m, tile_d)
         sums = jnp.dot(s_ref[...], g,
                        preferred_element_type=jnp.float32)
-        z_ref[:, pl.ds(i * tile_d, tile_d)] = sums / bsz_ref[...]
+        z_ref[i] = sums / bsz_ref[...]
 
         @pl.when(i == n_tiles - 1)
         def _finish():
-            _finish_round(z_ref[...], y_ref, trim_multiplier=trim_multiplier,
-                          max_iters=max_iters, tol=tol, eps=eps)
+            _finish_in_kernel(z_ref, y_ref, **kw)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -425,10 +485,9 @@ def linreg_round_kernel(features, targets, theta, grouping: Grouping, *,
     s = jnp.asarray(assignment_matrix(grouping))
     bsz = jnp.asarray(grouping.batch_sizes, jnp.float32).reshape(k, 1)
 
-    y, _, _ = pl.pallas_call(
+    y, _ = pl.pallas_call(
         functools.partial(_linreg_round_kernel, n_tiles=n_tiles,
-                          tile_d=tile_d, inv_n=1.0 / n,
-                          trim_multiplier=trim_multiplier,
+                          inv_n=1.0 / n, trim_multiplier=trim_multiplier,
                           max_iters=max_iters, tol=tol, eps=eps),
         grid=(2, n_tiles),
         in_specs=[
@@ -439,18 +498,17 @@ def linreg_round_kernel(features, targets, theta, grouping: Grouping, *,
             pl.BlockSpec((k, 1), lambda p, i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, d_pad), lambda p, i: (0, 0)),
+            pl.BlockSpec((n_tiles, 1, tile_d), lambda p, i: (0, 0, 0)),
             pl.BlockSpec((m, n), lambda p, i: (0, 0)),
-            pl.BlockSpec((k, d_pad), lambda p, i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles, 1, tile_d), jnp.float32),
             jax.ShapeDtypeStruct((m, n), jnp.float32),
-            jax.ShapeDtypeStruct((k, d_pad), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((n_tiles, k, tile_d), jnp.float32)],
         interpret=interpret,
     )(x, targets.astype(jnp.float32), theta_p, s, bsz)
-    return y[0, :d]
+    return y.reshape(-1)[:d]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -493,10 +551,9 @@ def linreg_round_ref(features, targets, theta, grouping: Grouping, *,
             preferred_element_type=jnp.float32) * inv_n
         tiles.append(jnp.dot(s, g, preferred_element_type=jnp.float32)
                      / bsz)
-    z = jnp.concatenate(tiles, axis=1) if n_tiles > 1 else tiles[0]
-    y = _means_trim_weiszfeld(z, k=k, trim_multiplier=trim_multiplier,
-                              max_iters=max_iters, tol=tol, eps=eps)
-    return y[0, :d]
+    y = _finish_jnp(jnp.stack(tiles), trim_multiplier=trim_multiplier,
+                    max_iters=max_iters, tol=tol, eps=eps)
+    return y[:d]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -526,6 +583,6 @@ def linreg_round_fused(features, targets, theta, grouping: Grouping, *,
         r, x, dimension_numbers=(((1,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * (1.0 / n)     # (m, d)
     z = jnp.dot(s, g, preferred_element_type=jnp.float32) / bsz
-    y = _means_trim_weiszfeld(z, k=k, trim_multiplier=trim_multiplier,
-                              max_iters=max_iters, tol=tol, eps=eps)
-    return y[0, :d]
+    y = _finish_jnp(_chunked(z, TILE_D), trim_multiplier=trim_multiplier,
+                    max_iters=max_iters, tol=tol, eps=eps)
+    return y[:d]

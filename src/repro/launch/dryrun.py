@@ -40,7 +40,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCHITECTURES, get_shape
 from repro.configs.base import ModelConfig
-from repro.models.meshctx import set_mesh
 from repro.core import RobustConfig, byzantine
 from repro.launch import mesh as mesh_lib
 from repro.launch import sharding, steps
@@ -161,7 +160,7 @@ def lower_pair(arch_or_cfg, shape_name: str, *, multi_pod: bool = False,
     num_chips = mesh.size
     t0 = time.time()
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params_s = steps.abstract_params(cfg)
         pshard = sharding.param_shardings(params_s, mesh, cfg, fsdp=fsdp)
 
@@ -170,40 +169,16 @@ def lower_pair(arch_or_cfg, shape_name: str, *, multi_pod: bool = False,
                 rc = default_train_rc(num_groups)
             opt = optim.adamw(3e-4)
             opt_s = steps.abstract_opt_state(opt, params_s)
-            oshard = sharding.opt_state_shardings(opt_s, params_s, mesh,
-                                                  cfg, fsdp=fsdp)
-            bshard = sharding.batch_shardings(batch, mesh)
-            if gather_grads:
-                gshard = sharding.gathered_grad_shardings(params_s, mesh)
-                spec = dataclasses.replace(
-                    sharding.grad_shard_spec(mesh, cfg), num_shards=1)
-            else:
-                gshard = sharding.stacked_grad_shardings(params_s, mesh, cfg,
-                                                         fsdp=fsdp)
-                spec = sharding.grad_shard_spec(mesh, cfg)
-            step_fn = steps.make_group_train_step(cfg, rc, opt,
-                                                  microbatches=microbatches,
-                                                  grad_shardings=gshard,
-                                                  schedule=schedule,
-                                                  shard_spec=spec)
+            jitted, _ = steps.jit_group_train_step(
+                cfg, rc, opt, params_s, opt_s, batch, mesh=mesh,
+                gather_grads=gather_grads, microbatches=microbatches,
+                schedule=schedule, fsdp=fsdp)
             key_s = jax.ShapeDtypeStruct((2,), jax.numpy.uint32)
             round_s = jax.ShapeDtypeStruct((), jax.numpy.int32)
-            rep = sharding.replicated(mesh)
-            if schedule is None:
-                jitted = jax.jit(
-                    step_fn,
-                    in_shardings=(pshard, oshard, bshard, rep, rep),
-                    donate_argnums=(0, 1))
-                lowered = jitted.lower(params_s, opt_s, batch, key_s, round_s)
-            else:
-                astate_s = jax.eval_shape(schedule.init_state)
-                ashard = jax.tree.map(lambda _: rep, astate_s)
-                jitted = jax.jit(
-                    step_fn,
-                    in_shardings=(pshard, oshard, bshard, rep, rep, ashard),
-                    donate_argnums=(0, 1))
-                lowered = jitted.lower(params_s, opt_s, batch, key_s,
-                                       round_s, astate_s)
+            args = (params_s, opt_s, batch, key_s, round_s)
+            if schedule is not None:
+                args += (jax.eval_shape(schedule.init_state),)
+            lowered = jitted.lower(*args)
             step_kind = "train_step"
 
         elif shape.kind == "prefill":

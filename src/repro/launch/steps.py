@@ -34,6 +34,7 @@ from repro.configs import get_config, get_shape, long_context_variant
 from repro.configs.base import InputShape, ModelConfig
 from repro.core import RobustConfig, byzantine
 from repro.core.robust_train import aggregate_reported
+from repro.launch import sharding
 from repro.models import model as model_lib
 
 
@@ -242,6 +243,54 @@ def make_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer, *,
                               attack_state)
 
     return train_step
+
+
+def jit_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer,
+                         params, opt_state, batch, *, mesh=None,
+                         gather_grads: bool = False, microbatches: int = 1,
+                         schedule: byzantine.AttackSchedule | None = None,
+                         fsdp: bool = True, target_backend: str = "tpu"):
+    """The group step, jitted with params and optimizer state donated.
+
+    ``mesh=None`` is one device and no shardings.  On a mesh the inputs take
+    the production shardings of ``launch.sharding`` and the stacked
+    gradients stay partitioned over ``model`` (the shard-local contract);
+    ``gather_grads=True`` instead constrains them fully replicated and
+    aggregates with a one-shard spec — the dense O(d)-per-device baseline.
+    ``params``, ``opt_state`` and ``batch`` may be arrays or
+    ShapeDtypeStructs: only their shapes are read.  ``target_backend`` keys
+    the round-backend dispatch (a dry run lowers TPU programs on a CPU host).
+
+    Returns ``(jitted_step, in_shardings)``; ``in_shardings`` is None
+    without a mesh, else ``(params, opt_state, batch, key, round[,
+    attack_state])`` shardings.
+    """
+    if mesh is None:
+        step = make_group_train_step(cfg, rc, optimizer,
+                                     microbatches=microbatches,
+                                     schedule=schedule)
+        return jax.jit(step, donate_argnums=(0, 1)), None
+    pshard = sharding.param_shardings(params, mesh, cfg, fsdp=fsdp)
+    oshard = sharding.opt_state_shardings(opt_state, params, mesh, cfg,
+                                          fsdp=fsdp)
+    bshard = sharding.batch_shardings(batch, mesh)
+    spec = sharding.grad_shard_spec(mesh, cfg, target_backend=target_backend)
+    if gather_grads:
+        gshard = sharding.gathered_grad_shardings(params, mesh)
+        spec = dataclasses.replace(spec, num_shards=1)
+    else:
+        gshard = sharding.stacked_grad_shardings(params, mesh, cfg, fsdp=fsdp)
+    step = make_group_train_step(cfg, rc, optimizer,
+                                 microbatches=microbatches,
+                                 grad_shardings=gshard, schedule=schedule,
+                                 shard_spec=spec)
+    rep = sharding.replicated(mesh)
+    in_shardings = (pshard, oshard, bshard, rep, rep)
+    if schedule is not None:
+        in_shardings += (jax.tree.map(
+            lambda _: rep, jax.eval_shape(schedule.init_state)),)
+    return (jax.jit(step, in_shardings=in_shardings, donate_argnums=(0, 1)),
+            in_shardings)
 
 
 def make_mean_train_step(cfg: ModelConfig, optimizer, *,

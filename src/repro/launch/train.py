@@ -1,24 +1,32 @@
 """End-to-end training driver.
 
-Two modes:
+Three modes:
 
-* ``--scale cpu`` (default): actually runs — reduced config, synthetic token
-  stream, worker-mode Byzantine GD (the paper-faithful path), checkpointing,
-  metrics log.  This is deliverable (b)'s end-to-end driver at CPU scale.
+* ``--scale cpu`` (default): reduced config, synthetic token stream,
+  worker-mode Byzantine GD (the paper-faithful path), checkpointing,
+  metrics log.
+* ``--scale device``: the production group-mode step
+  (``steps.make_group_train_step``) with real arrays on the local device,
+  at the architecture's one-chip share (``configs.get_chip_share``): every
+  published width, depth and vocabulary cut as its config file states.
 * ``--scale pod``: builds the production 16×16 (or 2×16×16) job with the
-  group-mode step and full-size config, and executes the dry-run lowering
-  (this container has no TPU; on real hardware the same code path runs by
-  passing real arrays instead of ShapeDtypeStructs).
+  group-mode step and full-size config, and lowers it on 512 virtual CPU
+  devices (a dry run: nothing executes).
 
 Usage:
     PYTHONPATH=src python -m repro.launch.train --arch minitron-4b \
         --steps 50 --byzantine 2 --attack sign_flip --aggregator gmom
+    PYTHONPATH=src python -m repro.launch.train --scale device \
+        --steps 6 --num-batches 4 --byzantine 1 --batch 4 --seq-len 4096
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
+import os
+import pathlib
 import time
 
 import jax
@@ -30,9 +38,37 @@ from repro.core import (RobustConfig, aggregators, byzantine,
                         restore_train_state, save_train_state,
                         schedule_from_config, staleness)
 from repro.core.train_state import advance, history_rows
-from repro.configs import ARCHITECTURES, get_config
+from repro.configs import ARCHITECTURES, get_chip_share, get_config
 from repro.data.tokens import TokenStream
+from repro.launch import steps as steps_lib
 from repro.models import model as model_lib
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache at one fixed path, which is
+    part of the cache key: the directory ``JAX_COMPILATION_CACHE_DIR``
+    names when it is set (JAX reads it itself), else ``<repo>/.jax_cache``.
+    Called by entry points, never at import."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_ROOT / ".jax_cache"))
+
+
+def device_description() -> dict:
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
+def peak_bytes_in_use():
+    """Largest ``peak_bytes_in_use`` over the local devices, or None where
+    the backend keeps no memory statistics."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.local_devices()]
+    peaks = [p for p in peaks if p is not None]
+    return max(peaks) if peaks else None
 
 
 def build_cpu_batch(cfg, stream: TokenStream, step: int, key):
@@ -165,6 +201,94 @@ def train_cpu(args) -> dict:
     return result
 
 
+def run_device_steps(cfg, rc: RobustConfig, optimizer, stream: TokenStream,
+                     *, steps: int, seed: int, mesh=None,
+                     gather_grads: bool = False) -> dict:
+    """Run ``steps`` group-mode robust steps on the local devices.
+
+    Parameters are initialised on the device from ``seed``; each step's
+    batch is ``stream.batch(step)``, made on the device.  ``mesh`` (None:
+    one device) and ``gather_grads`` select the layout as in
+    ``steps.jit_group_train_step``.  The step is compiled ahead of time, so
+    ``compile_seconds`` is set-up and each of ``step_seconds`` is one step
+    waited out with ``block_until_ready``.  Returns the final params and
+    optimizer state with the per-step metrics and timings."""
+    key = jax.random.PRNGKey(seed)
+    step_key = jax.random.fold_in(key, 10_000)
+    params_s = jax.eval_shape(lambda k: model_lib.init(k, cfg), key)
+    opt_s = jax.eval_shape(optimizer.init, params_s)
+    batch_s = jax.eval_shape(stream.batch, 0)
+    with jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext():
+        jitted, shard = steps_lib.jit_group_train_step(
+            cfg, rc, optimizer, params_s, opt_s, batch_s, mesh=mesh,
+            gather_grads=gather_grads, target_backend=jax.default_backend())
+        pshard, oshard, bshard = (None, None, None) if shard is None \
+            else shard[:3]
+        params = jax.jit(lambda k: model_lib.init(k, cfg),
+                         out_shardings=pshard)(key)
+        opt_state = jax.jit(optimizer.init, out_shardings=oshard)(params)
+        make_batch = jax.jit(stream.batch, out_shardings=bshard)
+        place = ((lambda x: x) if shard is None
+                 else (lambda x: jax.device_put(x, shard[3])))
+        t0 = time.perf_counter()
+        compiled = jitted.lower(params_s, opt_s, batch_s, step_key,
+                                jnp.int32(0)).compile()
+        compile_seconds = time.perf_counter() - t0
+        history, step_seconds = [], []
+        for i in range(steps):
+            batch = jax.block_until_ready(make_batch(i))
+            t0 = time.perf_counter()
+            params, opt_state, metrics = compiled(
+                params, opt_state, batch,
+                place(jax.random.fold_in(step_key, i)), place(jnp.int32(i)))
+            jax.block_until_ready((params, opt_state, metrics))
+            step_seconds.append(time.perf_counter() - t0)
+            history.append({k: float(v) for k, v in metrics.items()})
+    return {"params": params, "opt_state": opt_state, "history": history,
+            "compile_seconds": compile_seconds,
+            "step_seconds": step_seconds,
+            "peak_bytes_in_use": peak_bytes_in_use(),
+            "memory_analysis": compiled.memory_analysis()}
+
+
+def train_device(args) -> dict:
+    """The production group-mode step on the local device at the
+    architecture's one-chip share (``--scale device``)."""
+    cfg = get_chip_share(args.arch)
+    k = args.num_batches or 4
+    rc = RobustConfig(num_workers=k, num_byzantine=args.byzantine,
+                      num_batches=k, attack=args.attack,
+                      aggregator=args.aggregator,
+                      round_backend=args.round_backend)
+    stream = TokenStream(vocab_size=cfg.vocab_size, seq_len=args.seq_len,
+                         global_batch=args.batch, num_workers=k,
+                         seed=args.seed)
+    run = run_device_steps(cfg, rc, optim.adamw(args.lr), stream,
+                           steps=args.steps, seed=args.seed)
+    mem = run["memory_analysis"]
+    result = {"arch": cfg.name, "aggregator": args.aggregator,
+              "attack": args.attack, "num_batches": k,
+              "byzantine": args.byzantine, "device": device_description(),
+              "params": int(sum(x.size for x in
+                                jax.tree.leaves(run["params"]))),
+              "tokens_per_step": args.batch * args.seq_len,
+              "compile_seconds": run["compile_seconds"],
+              "step_seconds": run["step_seconds"],
+              "peak_bytes_in_use": run["peak_bytes_in_use"],
+              "compiled_temp_bytes": getattr(mem, "temp_size_in_bytes", None),
+              "history": run["history"]}
+    for i, (h, dt) in enumerate(zip(run["history"], run["step_seconds"])):
+        print(f"[train] step {i} loss_mean={h['loss_mean']:.6f} "
+              f"agg_grad_norm={h['agg_grad_norm']:.6f} step_s={dt:.6f}")
+    print(f"[train] {cfg.name} on {result['device']}: "
+          f"compile_s={run['compile_seconds']:.3f} "
+          f"peak_bytes_in_use={run['peak_bytes_in_use']}")
+    if args.out:
+        with open(args.out, "w") as f:
+            json.dump(result, f, indent=1)
+    return result
+
+
 def train_pod(args):
     from repro.launch import dryrun
     # dryrun no longer forces the 512 virtual host devices at import time;
@@ -181,7 +305,8 @@ def train_pod(args):
 def main(argv=None):
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--arch", default="minitron-4b", choices=list(ARCHITECTURES))
-    p.add_argument("--scale", default="cpu", choices=["cpu", "pod"])
+    p.add_argument("--scale", default="cpu",
+                   choices=["cpu", "device", "pod"])
     p.add_argument("--steps", type=int, default=50)
     p.add_argument("--workers", type=int, default=8)
     p.add_argument("--byzantine", type=int, default=2)
@@ -219,12 +344,14 @@ def main(argv=None):
     p.add_argument("--multi-pod", action="store_true")
     p.add_argument("--out", default=None)
     args = p.parse_args(argv)
-    if args.scale == "cpu":
-        train_cpu(args)
-    else:
+    if args.scale == "pod":
         # train_pod arms the 512 virtual host devices itself
         # (dryrun.force_host_device_count) — no pre-set XLA_FLAGS needed.
-        train_pod(args)
+        return train_pod(args)
+    use_compile_cache()
+    if args.scale == "device":
+        return train_device(args)
+    return train_cpu(args)
 
 
 if __name__ == "__main__":

@@ -278,7 +278,7 @@ def apply_sequence_parallel(params, spec: AttentionSpec, x, *, memory=None,
     mem_spec = P(dd, None, None)
     if memory is None:
         memory = jnp.zeros((B, 1, 1), x.dtype)   # placeholder, unused
-    shmap = meshctx.shard_map(
+    shmap = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(), params),
                   P(dd, "model", None), mem_spec),

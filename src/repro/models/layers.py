@@ -180,7 +180,7 @@ def swiglu(params, x):
             from jax.sharding import PartitionSpec as P
             dd = meshctx.dspec(mesh)
             region = _make_swiglu_sp_region(meshctx.data_axes(mesh))
-            return meshctx.shard_map(
+            return jax.shard_map(
                 region, mesh=mesh,
                 in_specs=(P(None, "model"), P(None, "model"),
                           P("model", None), P(dd, "model", None)),

@@ -11,55 +11,10 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = True):
-    """``jax.shard_map`` on new jax; the experimental module on 0.4.x.
-
-    ``check_rep=False`` disables the replication checker (needed around
-    ``lax.while_loop`` bodies on 0.4.x); newer jax dropped the kwarg, where
-    we simply ignore it.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_rep)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_rep)
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` on jax >= 0.6; on 0.4.x a ``Mesh`` is itself a context
-    manager that sets the thread-local resource env, so we return it as-is.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def current_mesh():
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        try:
-            mesh = getter()
-        except Exception:  # noqa: BLE001
-            return None
-        if mesh is None or not mesh.axis_names:
-            return None
-        return mesh
-    # jax 0.4.x: the ambient mesh lives in the thread-local resource env.
-    try:
-        from jax._src import mesh as _mesh_src
-        mesh = _mesh_src.thread_resources.env.physical_mesh
-    except Exception:  # noqa: BLE001
-        return None
-    if mesh is None or mesh.empty or not mesh.axis_names:
-        return None
-    return mesh
+    """The ambient mesh installed by ``jax.set_mesh``, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def data_axes(mesh) -> tuple[str, ...]:

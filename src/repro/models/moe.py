@@ -250,7 +250,7 @@ def _apply_expert_parallel(params, spec: MoESpec, x, mesh):
                 spec.router_aux_weight * aux + spec.router_z_weight * z)
 
     x_spec = P(d_ax, "model", None) if t_sharded else P(d_ax, None, None)
-    shmap = meshctx.shard_map(
+    shmap = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), _wspec(gate_fsdp_axis), _wspec(gate_fsdp_axis),

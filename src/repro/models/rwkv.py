@@ -183,16 +183,16 @@ def _wkv_dispatch(r, k, v, w, u, state):
         mp = meshctx.model_size(mesh)
         if B % dn == 0 and H % mp == 0 and dd is not None:
             spec4 = P(dd, None, "model", None)
-            # check_rep=False: jax 0.4.x's replication checker mis-infers
-            # the carry types when this region sits inside an outer
-            # lax.scan (the layer stack / microbatch loops).
-            return meshctx.shard_map(
+            # check_vma=False: the varying-axes checker mis-infers the
+            # carry types when this region sits inside an outer lax.scan
+            # (the layer stack / microbatch loops).
+            return jax.shard_map(
                 lambda *a: wkv_chunked(*a),
                 mesh=mesh,
                 in_specs=(spec4, spec4, spec4, spec4, P("model", None),
                           P(dd, "model", None, None)),
                 out_specs=(spec4, P(dd, "model", None, None)),
-                check_rep=False,
+                check_vma=False,
             )(r, k, v, w, u, state)
     return wkv_chunked(r, k, v, w, u, state)
 

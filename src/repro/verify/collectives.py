@@ -21,9 +21,9 @@ import dataclasses
 
 from repro.roofline import hlo_parser
 
-# primitive names across the supported jax version range (0.4.x floor —
-# current): shard_map lowers lax.psum to psum2/psum_invariant on some
-# versions, all_gather keeps its name everywhere.
+# collective primitive names as they appear in jaxprs (older releases
+# spelled shard_map's psum ``psum2``; jax 0.9 keeps ``psum`` and adds the
+# ``*_invariant`` forms of the varying-axes system).
 COLLECTIVE_PRIMS = frozenset({
     "psum", "psum2", "psum_invariant", "pmax", "pmin",
     "all_gather", "all_gather_invariant", "all_to_all", "ppermute",

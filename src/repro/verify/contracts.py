@@ -111,13 +111,13 @@ def _sharded_fn(name: str, num_shards: int, scale: int, *, seed: int,
     import jax
     from jax.sharding import PartitionSpec as P
     from repro.core.robust_train import make_sharded_aggregate
-    from repro.models.meshctx import shard_map
+    from repro.launch.mesh import make_mesh
 
     axis = "model"
     cfg = harness_cfg(name, codec=codec)
     stacked = harness_tree(HARNESS_M, scale)
     key = jax.random.PRNGKey(seed)
-    mesh = jax.make_mesh((num_shards,), (axis,))
+    mesh = make_mesh((num_shards,), (axis,))
     in_specs, _ = _specs(stacked, axis)
     # aggregation drops the leading worker axis of every leaf — derive the
     # output specs structurally rather than via eval_shape (which would run
@@ -128,8 +128,8 @@ def _sharded_fn(name: str, num_shards: int, scale: int, *, seed: int,
                    else P(*((None,) * (x.ndim - 2) + (axis,)))),
         stacked)
     agg = make_sharded_aggregate(cfg, mesh, axis=axis)
-    fn = shard_map(agg, mesh=mesh, in_specs=(in_specs, P(None)),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(agg, mesh=mesh, in_specs=(in_specs, P(None)),
+                       out_specs=out_specs, check_vma=False)
     return fn, (stacked, key)
 
 

@@ -21,8 +21,8 @@ import itertools
 
 from repro.verify.rules import Finding
 
-# grid spanning the budget boundary: with k=64 the (k+1)*d_pad term
-# crosses 8 MiB between d=7680 and d=8192, so both guard outcomes occur.
+# grid spanning the budget boundary: with k=64 the resident block crosses
+# the budget between d=32768 and d=131072, so both guard outcomes occur.
 GRID_M = (8, 50, 128)
 GRID_K = (4, 11, 25, 64)
 GRID_D = (100, 512, 4096, 7680, 8192, 32768, 131072)
@@ -39,7 +39,7 @@ def _guard_ok(round_mod, m: int, k: int, d: int) -> bool:
     d_pad = -(-d // tile_d) * tile_d
     try:
         round_mod._check_vmem(k, d_pad,
-                              extra_bytes=(m * tile_d + k * m) * 4)
+                              extra_bytes=round_mod._tile_bytes(m, k, tile_d))
         return True
     except ValueError:
         return False

@@ -18,7 +18,6 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.models.meshctx import set_mesh
     from repro.configs import get_config
     from repro.configs.base import InputShape
     from repro.core import RobustConfig
@@ -30,7 +29,7 @@ SCRIPT = textwrap.dedent("""
     kind = "{kind}"
     mesh = mesh_lib.make_debug_mesh(data=2, model=2, pod=2)
     cfg = get_config(arch).reduced()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params_s = steps.abstract_params(cfg)
         pshard = sharding.param_shardings(params_s, mesh, cfg)
         if kind == "train":

@@ -18,9 +18,9 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro.models.meshctx import set_mesh
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     key = jax.random.PRNGKey(0)
 
     # --- sequence-parallel attention == dense ------------------------------
@@ -35,7 +35,7 @@ SCRIPT = textwrap.dedent("""
         return jnp.sum(attention.apply_sequence_parallel(
             p, spec, x, q_block=32, kv_block=32) ** 2)
     g_ref = jax.grad(lambda p, x: jnp.sum(attention.apply(p, spec, x)**2))(p, x)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sp = jax.jit(lambda pp, xx: attention.apply_sequence_parallel(
             pp, spec, xx, q_block=32, kv_block=32))(p, x)
         g_sp = jax.jit(jax.grad(loss_sp))(p, x)
@@ -54,7 +54,7 @@ SCRIPT = textwrap.dedent("""
         o, a = moe.apply(p, mspec, xx)
         return jnp.sum(o ** 2) + a
     gm_ref = jax.grad(mloss)(mp, xm)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out_ep, aux_ep = jax.jit(lambda p, xx: moe.apply(p, mspec, xx))(mp, xm)
         gm_ep = jax.jit(jax.grad(mloss))(mp, xm)
     assert float(jnp.max(jnp.abs(out_ref - out_ep))) < 1e-5, "EP fwd"
@@ -73,7 +73,7 @@ SCRIPT = textwrap.dedent("""
         return jnp.sum(layers.swiglu(p, xx) ** 2)
     gs_ref = jax.grad(lambda p, xx: jnp.sum(layers._swiglu_local(
         p["w_gate"], p["w_up"], p["w_down"], xx) ** 2))(sp_params, xs)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out_s = jax.jit(lambda p, xx: layers.swiglu(p, xx))(sp_params, xs)
         gs = jax.jit(jax.grad(sloss))(sp_params, xs)
     assert float(jnp.max(jnp.abs(ref_s - out_s))) < 1e-4, "swiglu fwd"
@@ -90,7 +90,7 @@ SCRIPT = textwrap.dedent("""
     u = jax.random.normal(ks[4], (H, hd)) * 0.1
     S0 = jax.random.normal(jax.random.PRNGKey(6), (B, H, hd, hd))
     y_ref, f_ref = rwkv.wkv_scan(r, k, v, w, u, S0)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y, f = jax.jit(rwkv._wkv_dispatch)(r, k, v, w, u, S0)
     assert float(jnp.max(jnp.abs(y_ref - y))) < 1e-3, "wkv"
     assert float(jnp.max(jnp.abs(f_ref - f))) < 1e-3, "wkv state"
@@ -104,7 +104,7 @@ SCRIPT = textwrap.dedent("""
                              0, cfg.vocab_size)
     batch = {"tokens": tok, "labels": jnp.roll(tok, -1, -1)}
     l_ref = float(M.loss_fn(params, batch, cfg))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         l_mesh = float(jax.jit(
             lambda p, b: M.loss_fn(p, b, cfg))(params, batch))
     assert abs(l_ref - l_mesh) < 1e-3, (l_ref, l_mesh)

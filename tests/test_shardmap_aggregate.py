@@ -21,10 +21,10 @@ SCRIPT = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core import RobustConfig, aggregate, aggregators, \\
         make_shardmap_aggregate
-    from repro.models.meshctx import shard_map
+    from repro.launch.mesh import make_mesh
 
     m, k = 8, 4
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     cfg = RobustConfig(num_workers=m, num_byzantine=1, num_batches=k,
                        attack="none", aggregator="gmom",
                        gmom_max_iters=32, gmom_tol=1e-7)
@@ -47,9 +47,9 @@ SCRIPT = textwrap.dedent("""
     specs = jax.tree.map(
         lambda x: P(*(("data",) + (None,) * (x.ndim - 1))), stacked)
     out_specs = jax.tree.map(lambda x: P(*((None,) * (x.ndim - 1))), stacked)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda s: agg_local(jax.tree.map(lambda x: x[0], s)),
-        mesh=mesh, in_specs=(specs,), out_specs=out_specs, check_rep=False)
+        mesh=mesh, in_specs=(specs,), out_specs=out_specs, check_vma=False)
     handsched = jax.jit(fn)(stacked)
 
     # --- single-device oracle
@@ -70,9 +70,9 @@ SCRIPT = textwrap.dedent("""
     import dataclasses
     cfg_fused = dataclasses.replace(cfg, round_backend="fused_interpret")
     agg_fused = make_shardmap_aggregate(cfg_fused, mesh)
-    fn_fused = shard_map(
+    fn_fused = jax.shard_map(
         lambda s: agg_fused(jax.tree.map(lambda x: x[0], s)),
-        mesh=mesh, in_specs=(specs,), out_specs=out_specs, check_rep=False)
+        mesh=mesh, in_specs=(specs,), out_specs=out_specs, check_vma=False)
     handsched_fused = jax.jit(fn_fused)(stacked)
 
     oracle_fused = aggregators.gmom_aggregator(
@@ -115,10 +115,10 @@ BLOCKED_SCRIPT = textwrap.dedent("""
     from repro.core import RobustConfig, aggregators, aggregate_reported, \\
         make_sharded_aggregate
     from repro.core.shard_aggregation import ShardSpec
-    from repro.models.meshctx import shard_map
+    from repro.launch.mesh import make_mesh
 
     m, S = 8, 8
-    mesh = jax.make_mesh((S,), ("model",))
+    mesh = make_mesh((S,), ("model",))
     key = jax.random.PRNGKey(7)
     ks = jax.random.split(key, 3)
     base = {"w": jax.random.normal(ks[0], (m, 16), jnp.float32),
@@ -162,8 +162,9 @@ BLOCKED_SCRIPT = textwrap.dedent("""
                     out_spec, jax.eval_shape(
                         lambda s: aggregate_reported(s, cfg, key=key),
                         stacked))
-                fn = shard_map(agg, mesh=mesh, in_specs=(in_specs, P(None)),
-                               out_specs=out_specs, check_rep=False)
+                fn = jax.shard_map(agg, mesh=mesh,
+                                   in_specs=(in_specs, P(None)),
+                                   out_specs=out_specs, check_vma=False)
                 sharded = jax.jit(fn)(stacked, key)
 
                 for pa, b in zip(
