@@ -129,6 +129,75 @@ def abstract_opt_state(optimizer, params_struct):
 # ---------------------------------------------------------------------------
 # steps
 
+def make_group_grads(cfg: ModelConfig, *, microbatches: int = 1):
+    """``(params, batch) -> (losses (k,), stacked grads (k, *param))``.
+
+    A sequential scan over the k batch-groups (gradient accumulation with
+    per-group gradients kept separate): one group's activations live at a
+    time, and shard_map regions (MoE EP) stay legal.  Each group is itself
+    data-parallel over the full data axis."""
+    def group_value_and_grad(params, group_batch):
+        if microbatches == 1:
+            return jax.value_and_grad(model_lib.loss_fn)(
+                params, group_batch, cfg)
+
+        def reshape(x):
+            n = x.shape[0]
+            assert n % microbatches == 0
+            return x.reshape((microbatches, n // microbatches) + x.shape[1:])
+
+        mb = jax.tree.map(reshape, group_batch)
+        zero = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+
+        def mb_step(carry, b):
+            g_acc, l_acc = carry
+            l, g = jax.value_and_grad(model_lib.loss_fn)(params, b, cfg)
+            g_acc = jax.tree.map(
+                lambda a, x: a + x.astype(jnp.float32), g_acc, g)
+            return (g_acc, l_acc + l), None
+
+        (g, l), _ = jax.lax.scan(
+            mb_step, (zero, jnp.zeros((), jnp.float32)), mb)
+        inv = 1.0 / microbatches
+        return l * inv, jax.tree.map(lambda x: x * inv, g)
+
+    def group_grads(params, batch):
+        def group_step(_, group_batch):
+            return None, group_value_and_grad(params, group_batch)
+
+        _, (losses, grads) = jax.lax.scan(group_step, None, batch)
+        return losses, grads
+
+    return group_grads
+
+
+def report_groups(grads, rc: RobustConfig, key, round_index):
+    """The reports the server receives for stacked group gradients:
+    ``rc.num_byzantine`` of the ``rc.num_workers`` groups, drawn from
+    ``key``, replaced by ``rc.attack``.  Returns ``(reported, mask)``."""
+    mask = byzantine.sample_byzantine_mask(
+        key, rc.num_workers, rc.num_byzantine, rotate=rc.rotate_byzantine,
+        round_index=round_index)
+    attack = byzantine.get_attack(rc.attack)
+    return attack(grads, mask, key, **dict(rc.attack_kwargs)), mask
+
+
+def group_grad_layout(params, mesh, cfg: ModelConfig, *,
+                      gather_grads: bool = False, fsdp: bool = True,
+                      target_backend: str = "tpu"):
+    """``(stacked grad shardings, ShardSpec)`` of the group step on ``mesh``.
+
+    Shard-local: the stacked gradients keep the param layout, partitioned
+    over ``model``.  ``gather_grads``: fully replicated, aggregated with a
+    one-shard spec — the dense O(d)-per-device baseline."""
+    spec = sharding.grad_shard_spec(mesh, cfg, target_backend=target_backend)
+    if gather_grads:
+        return (sharding.gathered_grad_shardings(params, mesh),
+                dataclasses.replace(spec, num_shards=1))
+    return (sharding.stacked_grad_shardings(params, mesh, cfg, fsdp=fsdp),
+            spec)
+
+
 def make_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer, *,
                           microbatches: int = 1, grad_shardings=None,
                           schedule: byzantine.AttackSchedule | None = None,
@@ -168,53 +237,14 @@ def make_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer, *,
     -> (params, opt_state, metrics, attack_state)``; without it the
     historical 5-arg signature is unchanged.
     """
-    k = rc.num_workers
-
-    def group_value_and_grad(params, group_batch):
-        if microbatches == 1:
-            return jax.value_and_grad(model_lib.loss_fn)(
-                params, group_batch, cfg)
-
-        def reshape(x):
-            n = x.shape[0]
-            assert n % microbatches == 0
-            return x.reshape((microbatches, n // microbatches) + x.shape[1:])
-
-        mb = jax.tree.map(reshape, group_batch)
-        zero = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-
-        def mb_step(carry, b):
-            g_acc, l_acc = carry
-            l, g = jax.value_and_grad(model_lib.loss_fn)(params, b, cfg)
-            g_acc = jax.tree.map(
-                lambda a, x: a + x.astype(jnp.float32), g_acc, g)
-            return (g_acc, l_acc + l), None
-
-        (g, l), _ = jax.lax.scan(
-            mb_step, (zero, jnp.zeros((), jnp.float32)), mb)
-        inv = 1.0 / microbatches
-        return l * inv, jax.tree.map(lambda x: x * inv, g)
-
-    attack = byzantine.get_attack(rc.attack)
-    attack_kwargs = dict(rc.attack_kwargs)
+    group_grads = make_group_grads(cfg, microbatches=microbatches)
 
     def _step_core(params, opt_state, batch, key, round_index, attack_state):
-        # sequential scan over the k batch-groups (gradient accumulation
-        # with per-group gradients kept separate): one group's activations
-        # live at a time, and shard_map regions (MoE EP) stay legal.  Each
-        # group is itself data-parallel over the full data axis.
-        def group_step(_, group_batch):
-            loss, grad = group_value_and_grad(params, group_batch)
-            return None, (loss, grad)
-
-        _, (losses, grads) = jax.lax.scan(group_step, None, batch)
+        losses, grads = group_grads(params, batch)
         if grad_shardings is not None:
             grads = jax.lax.with_sharding_constraint(grads, grad_shardings)
         if schedule is None:
-            mask = byzantine.sample_byzantine_mask(
-                key, k, rc.num_byzantine, rotate=rc.rotate_byzantine,
-                round_index=round_index)
-            reported = attack(grads, mask, key, **attack_kwargs)
+            reported, mask = report_groups(grads, rc, key, round_index)
         else:
             reported, mask, attack_state = schedule.apply(
                 grads, key, round_index, attack_state)
@@ -274,12 +304,9 @@ def jit_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer,
     oshard = sharding.opt_state_shardings(opt_state, params, mesh, cfg,
                                           fsdp=fsdp)
     bshard = sharding.batch_shardings(batch, mesh)
-    spec = sharding.grad_shard_spec(mesh, cfg, target_backend=target_backend)
-    if gather_grads:
-        gshard = sharding.gathered_grad_shardings(params, mesh)
-        spec = dataclasses.replace(spec, num_shards=1)
-    else:
-        gshard = sharding.stacked_grad_shardings(params, mesh, cfg, fsdp=fsdp)
+    gshard, spec = group_grad_layout(params, mesh, cfg,
+                                     gather_grads=gather_grads, fsdp=fsdp,
+                                     target_backend=target_backend)
     step = make_group_train_step(cfg, rc, optimizer,
                                  microbatches=microbatches,
                                  grad_shardings=gshard, schedule=schedule,

@@ -44,13 +44,20 @@ from repro.launch import steps as steps_lib
 from repro.models import model as model_lib
 
 REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+# At minitron-4b's widths a constant AdamW lr of 1e-4 or more throws the
+# loss after the first step above its uniform-guess value, with or without
+# an attack; warming up to 1e-3 over 1000 steps (1e-6 more each step), the
+# loss falls at every step (PERF.md, PR 11 findings).
+DEVICE_WARMUP_STEPS = 1000
 
 
 def use_compile_cache() -> None:
     """Keep JAX's persistent compilation cache at one fixed path, which is
     part of the cache key: the directory ``JAX_COMPILATION_CACHE_DIR``
     names when it is set (JAX reads it itself), else ``<repo>/.jax_cache``.
-    Called by entry points, never at import."""
+    Called by the command-line entry points (``python -m
+    repro.launch.train``, ``chip_smoke.py``), never at import and never by
+    ``main(argv)``, which tests call in-process."""
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir",
                           str(REPO_ROOT / ".jax_cache"))
@@ -69,6 +76,14 @@ def peak_bytes_in_use():
              for d in jax.local_devices()]
     peaks = [p for p in peaks if p is not None]
     return max(peaks) if peaks else None
+
+
+def learning_rate(args):
+    """``--lr``, reached by a linear warmup over ``--warmup-steps``."""
+    if not args.warmup_steps:
+        return args.lr
+    return optim.schedule.linear_warmup(args.lr,
+                                        warmup_steps=args.warmup_steps)
 
 
 def build_cpu_batch(cfg, stream: TokenStream, step: int, key):
@@ -137,7 +152,7 @@ def train_cpu(args) -> dict:
                       round_backend=args.round_backend,
                       arrival=args.arrival,
                       staleness_bound=args.staleness_bound)
-    opt = optim.adamw(args.lr)
+    opt = optim.adamw(learning_rate(args))
     loss_fn = lambda p, b: model_lib.loss_fn(p, b, cfg)  # noqa: E731
     if args.schedule:
         schedule = byzantine.make_schedule(
@@ -201,6 +216,13 @@ def train_cpu(args) -> dict:
     return result
 
 
+def device_step_key(seed: int, step: int):
+    """The PRNG key of step ``step`` of :func:`run_device_steps`: it draws
+    the Byzantine groups and the attack's and aggregator's randomness."""
+    return jax.random.fold_in(
+        jax.random.fold_in(jax.random.PRNGKey(seed), 10_000), step)
+
+
 def run_device_steps(cfg, rc: RobustConfig, optimizer, stream: TokenStream,
                      *, steps: int, seed: int, mesh=None,
                      gather_grads: bool = False) -> dict:
@@ -214,7 +236,6 @@ def run_device_steps(cfg, rc: RobustConfig, optimizer, stream: TokenStream,
     waited out with ``block_until_ready``.  Returns the final params and
     optimizer state with the per-step metrics and timings."""
     key = jax.random.PRNGKey(seed)
-    step_key = jax.random.fold_in(key, 10_000)
     params_s = jax.eval_shape(lambda k: model_lib.init(k, cfg), key)
     opt_s = jax.eval_shape(optimizer.init, params_s)
     batch_s = jax.eval_shape(stream.batch, 0)
@@ -231,7 +252,8 @@ def run_device_steps(cfg, rc: RobustConfig, optimizer, stream: TokenStream,
         place = ((lambda x: x) if shard is None
                  else (lambda x: jax.device_put(x, shard[3])))
         t0 = time.perf_counter()
-        compiled = jitted.lower(params_s, opt_s, batch_s, step_key,
+        compiled = jitted.lower(params_s, opt_s, batch_s,
+                                device_step_key(seed, 0),
                                 jnp.int32(0)).compile()
         compile_seconds = time.perf_counter() - t0
         history, step_seconds = [], []
@@ -240,7 +262,7 @@ def run_device_steps(cfg, rc: RobustConfig, optimizer, stream: TokenStream,
             t0 = time.perf_counter()
             params, opt_state, metrics = compiled(
                 params, opt_state, batch,
-                place(jax.random.fold_in(step_key, i)), place(jnp.int32(i)))
+                place(device_step_key(seed, i)), place(jnp.int32(i)))
             jax.block_until_ready((params, opt_state, metrics))
             step_seconds.append(time.perf_counter() - t0)
             history.append({k: float(v) for k, v in metrics.items()})
@@ -263,12 +285,14 @@ def train_device(args) -> dict:
     stream = TokenStream(vocab_size=cfg.vocab_size, seq_len=args.seq_len,
                          global_batch=args.batch, num_workers=k,
                          seed=args.seed)
-    run = run_device_steps(cfg, rc, optim.adamw(args.lr), stream,
+    run = run_device_steps(cfg, rc, optim.adamw(learning_rate(args)), stream,
                            steps=args.steps, seed=args.seed)
     mem = run["memory_analysis"]
     result = {"arch": cfg.name, "aggregator": args.aggregator,
               "attack": args.attack, "num_batches": k,
-              "byzantine": args.byzantine, "device": device_description(),
+              "byzantine": args.byzantine, "lr": args.lr,
+              "warmup_steps": args.warmup_steps,
+              "device": device_description(),
               "params": int(sum(x.size for x in
                                 jax.tree.leaves(run["params"]))),
               "tokens_per_step": args.batch * args.seq_len,
@@ -336,6 +360,12 @@ def main(argv=None):
     p.add_argument("--batch", type=int, default=16)
     p.add_argument("--seq-len", type=int, default=64)
     p.add_argument("--lr", type=float, default=1e-3)
+    p.add_argument("--warmup-steps", type=int, default=None,
+                   dest="warmup_steps",
+                   help="linear lr warmup to --lr over this many steps (0: "
+                        "constant; default 1000 with --scale device, else "
+                        "0): AdamW's first steps move every weight by about "
+                        "the lr, which at published widths diverges")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--log-every", type=int, default=10)
     p.add_argument("--ckpt-dir", default=None)
@@ -344,15 +374,17 @@ def main(argv=None):
     p.add_argument("--multi-pod", action="store_true")
     p.add_argument("--out", default=None)
     args = p.parse_args(argv)
+    if args.warmup_steps is None:
+        args.warmup_steps = DEVICE_WARMUP_STEPS if args.scale == "device" else 0
     if args.scale == "pod":
         # train_pod arms the 512 virtual host devices itself
         # (dryrun.force_host_device_count) — no pre-set XLA_FLAGS needed.
         return train_pod(args)
-    use_compile_cache()
     if args.scale == "device":
         return train_device(args)
     return train_cpu(args)
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -9,6 +9,15 @@ def constant(lr: float):
     return lambda step: jnp.asarray(lr, jnp.float32)
 
 
+def linear_warmup(peak_lr: float, *, warmup_steps: int):
+    """``peak_lr * min(1, (step + 1) / warmup_steps)``, then constant: the
+    first update already moves, by ``peak_lr / warmup_steps``."""
+    def sched(step):
+        frac = (step.astype(jnp.float32) + 1.0) / max(warmup_steps, 1)
+        return peak_lr * jnp.minimum(frac, 1.0)
+    return sched
+
+
 def warmup_cosine(peak_lr: float, *, warmup_steps: int, total_steps: int,
                   final_fraction: float = 0.1):
     def sched(step):

@@ -11,14 +11,17 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import numpy as np
 
 from repro import optim
 from repro.configs import get_chip_share, get_config
 from repro.configs import minitron_4b
 from repro.core import RobustConfig
+from repro.core.robust_train import aggregate_reported
 from repro.data.tokens import TokenStream
-from repro.launch import train
+from repro.launch import steps, train
+from repro.models import model as model_lib
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -47,16 +50,39 @@ def test_run_device_steps_one_device():
     assert again["history"] == run["history"]
 
 
+def test_step_halves_reproduce_the_step():
+    """``make_group_grads`` then ``report_groups`` and the aggregation give
+    the aggregate the step hands AdamW (its first moment over 1 - b1): the
+    four-chip smoke compares layouts half by half on that basis."""
+    cfg, rc, stream = _setup()
+    run = train.run_device_steps(cfg, rc, optim.adamw(1e-3, b1=0.9), stream,
+                                 steps=1, seed=0)
+    params = model_lib.init(jax.random.PRNGKey(0), cfg)
+    key = train.device_step_key(0, 0)
+    losses, grads = steps.make_group_grads(cfg)(params, stream.batch(0))
+    reported, mask = steps.report_groups(grads, rc, key, 0)
+    agg = aggregate_reported(reported, rc, key=key)
+    assert int(mask.sum()) == 1
+    assert np.isclose(float(losses.mean()), run["history"][0]["loss_mean"])
+    for a, m in zip(jax.tree.leaves(agg), jax.tree.leaves(run["opt_state"].mu)):
+        np.testing.assert_allclose(a, m / (1 - 0.9), rtol=1e-5, atol=1e-8)
+
+
 def test_device_mode_cli(monkeypatch, tmp_path):
     """``--scale device`` runs the chip share; here the reduced config
     stands in for it so the CLI wiring runs on the CPU."""
     monkeypatch.setattr(train, "get_chip_share",
                         lambda arch: get_config(arch).reduced())
     out = tmp_path / "r.json"
+    cache_dir = jax.config.jax_compilation_cache_dir
     res = train.main(["--scale", "device", "--steps", "2",
                       "--num-batches", "4", "--byzantine", "1",
                       "--batch", "8", "--seq-len", "32", "--out", str(out)])
+    # main(argv) leaves the compile cache to the command-line entry points
+    assert jax.config.jax_compilation_cache_dir == cache_dir
     assert res["device"]["platform"] == "cpu"
+    assert (res["lr"], res["warmup_steps"]) == (1e-3,
+                                                train.DEVICE_WARMUP_STEPS)
     assert res["tokens_per_step"] == 8 * 32
     assert len(res["history"]) == 2
     assert json.loads(out.read_text())["history"] == res["history"]

@@ -127,3 +127,9 @@ def test_schedules():
     inv = schedule.inverse_sqrt(1.0, warmup_steps=16)
     assert float(inv(jnp.asarray(16))) == 1.0
     assert abs(float(inv(jnp.asarray(64))) - 0.5) < 1e-5
+    # linear_warmup moves from the first step
+    s = schedule.linear_warmup(1e-3, warmup_steps=1000)
+    assert np.isclose(float(s(jnp.asarray(0))), 1e-6)
+    assert np.isclose(float(s(jnp.asarray(499))), 5e-4)
+    assert np.isclose(float(s(jnp.asarray(999))), 1e-3)
+    assert np.isclose(float(s(jnp.asarray(5000))), 1e-3)
