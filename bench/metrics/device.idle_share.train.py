@@ -1,0 +1,6 @@
+"""Share of the traced window of training steps in which no operation ran
+on the device, averaged over the chips."""
+
+
+def read(r):
+    return 100.0 * r.trace.idle_share()
