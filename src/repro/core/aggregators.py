@@ -400,7 +400,7 @@ def gmom_aggregator(stacked_grads, *, num_batches: int | None = None,
                     trim_multiplier: float | None = 3.0,
                     max_iters: int = 64, tol: float = 1e-8,
                     round_backend: str | None = "auto",
-                    shard_spec=None, **_kw):
+                    shard_spec=None, info: dict | None = None, **_kw):
     """Paper Algorithm 2 step 4: A_k(g) = med{batch means}, with the
     Remark-2 norm trimming applied as zero Weiszfeld weights.
 
@@ -411,6 +411,12 @@ def gmom_aggregator(stacked_grads, *, num_batches: int | None = None,
     gather) and routes every distance/norm reduction through
     :func:`repro.core.shard_aggregation.blocked_partial_sum` — one (k,)
     reduction per Weiszfeld iterate, nothing of size d ever crosses shards.
+
+    ``info`` (a dict) receives the reference path's Weiszfeld step count
+    under ``"weiszfeld_iters"``; the fused kernel keeps its loop counter in
+    VMEM and reports none.  Each stage runs under a ``jax.named_scope``
+    (``batch_means``, ``trim``, ``weiszfeld``; ``round_kernel`` in the
+    kernel's front door) so a profile of the step finds it.
     """
     from repro.core import shard_aggregation as _sa
     m = _num_workers(stacked_grads)
@@ -432,14 +438,18 @@ def gmom_aggregator(stacked_grads, *, num_batches: int | None = None,
             max_iters=max_iters, tol=tol,
             use_pallas=(backend == "fused"),
             interpret=(backend == "fused_interpret"))
-    means = batch_means(stacked_grads, num_batches, scheme=grouping_scheme)
+    with jax.named_scope("batch_means"):
+        means = batch_means(stacked_grads, num_batches,
+                            scheme=grouping_scheme)
     weights = None
     if trim_multiplier is not None:
-        norms = batch_mean_norms(means, shard_spec=shard_spec)
-        weights = trim_weights(norms, multiplier=trim_multiplier)
-    return geometric_median_pytree(means, weights=weights,
-                                      max_iters=max_iters, tol=tol,
-                                      shard_spec=shard_spec)
+        with jax.named_scope("trim"):
+            norms = batch_mean_norms(means, shard_spec=shard_spec)
+            weights = trim_weights(norms, multiplier=trim_multiplier)
+    with jax.named_scope("weiszfeld"):
+        return geometric_median_pytree(means, weights=weights,
+                                       max_iters=max_iters, tol=tol,
+                                       shard_spec=shard_spec, info=info)
 
 
 @register("geomed", "geometric median of the raw worker gradients — the "
@@ -447,11 +457,14 @@ def gmom_aggregator(stacked_grads, *, num_batches: int | None = None,
           needs_shard_spec=True, shard_contract="norm_based",
           sanitization_point="weiszfeld")
 def geomed_aggregator(stacked_grads, *, max_iters: int = 64,
-                      tol: float = 1e-8, shard_spec=None, **_kw):
+                      tol: float = 1e-8, shard_spec=None,
+                      info: dict | None = None, **_kw):
     """GMoM with every worker its own batch (k = m, paper §2.1): maximal
     robustness per report, no variance reduction from batching."""
-    return geometric_median_pytree(stacked_grads, max_iters=max_iters,
-                                      tol=tol, shard_spec=shard_spec)
+    with jax.named_scope("weiszfeld"):
+        return geometric_median_pytree(stacked_grads, max_iters=max_iters,
+                                       tol=tol, shard_spec=shard_spec,
+                                       info=info)
 
 
 @register("coordinate_median", "coordinate-wise median — the marginal-"
@@ -781,7 +794,8 @@ def norm_filter_gmom_aggregator(stacked_grads, *,
                                 trim_multiplier: float | None = 3.0,
                                 max_iters: int = 64, tol: float = 1e-8,
                                 round_backend: str | None = "auto",
-                                shard_spec=None, **_kw):
+                                shard_spec=None, info: dict | None = None,
+                                **_kw):
     """Two-sided norm filter -> geometric median of means (the §6
     "combined selection rule", in the filtering style of Su & Xu '18).
 
@@ -854,7 +868,7 @@ def norm_filter_gmom_aggregator(stacked_grads, *,
                            trim_multiplier=trim_multiplier,
                            max_iters=max_iters, tol=tol,
                            round_backend=round_backend,
-                           shard_spec=shard_spec)
+                           shard_spec=shard_spec, info=info)
 
 
 # ---------------------------------------------------------------------------
@@ -958,7 +972,7 @@ def int8_gmom_aggregator(stacked_grads, *, like=None,
                          trim_multiplier: float | None = 3.0,
                          max_iters: int = 64, tol: float = 1e-8,
                          round_backend: str | None = "auto",
-                         shard_spec=None, **_kw):
+                         shard_spec=None, info: dict | None = None, **_kw):
     """Dequantize-then-GMoM: the int8 payload (q values + per-worker
     scales) is expanded back to ``like``'s dtype in-rule, then the paper's
     Algorithm 2 pipeline runs unchanged — including the ``round_backend``
@@ -975,4 +989,4 @@ def int8_gmom_aggregator(stacked_grads, *, like=None,
                            trim_multiplier=trim_multiplier,
                            max_iters=max_iters, tol=tol,
                            round_backend=round_backend,
-                           shard_spec=shard_spec)
+                           shard_spec=shard_spec, info=info)

@@ -105,7 +105,8 @@ def geometric_median_pytree(batch_means, *,
                             max_iters: int = 64,
                             tol: float = 1e-8,
                             eps: float = 1e-12,
-                            shard_spec=None):
+                            shard_spec=None,
+                            info: dict | None = None):
     """Geometric median of k *pytrees* (paper-faithful "global" mode).
 
     ``batch_means`` is a pytree whose leaves have a leading axis k (the batch
@@ -122,6 +123,9 @@ def geometric_median_pytree(batch_means, *,
     the scalar movement cross shards — ONE small blocked reduction per
     iterate.  With a trivial spec (None / gspmd) the reductions follow the
     legacy accumulation order (golden traces stay within tolerance).
+
+    ``info`` (a dict) receives ``"weiszfeld_iters"``: the loop's final
+    counter, the Weiszfeld steps taken (int32, at most ``max_iters``).
 
     Returns a pytree of the same structure without the leading axis.
     """
@@ -186,9 +190,11 @@ def geometric_median_pytree(batch_means, *,
         y_new = step(y)
         return (y_new, it + 1, flat_delta(y_new, y))
 
-    y, _, _ = jax.lax.while_loop(
+    y, it, _ = jax.lax.while_loop(
         cond, body, (y0, jnp.zeros((), jnp.int32),
                      jnp.array(jnp.inf, jnp.float32)))
+    if info is not None:
+        info["weiszfeld_iters"] = it
     return jax.tree.unflatten(treedef, y)
 
 

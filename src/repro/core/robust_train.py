@@ -102,7 +102,7 @@ def per_worker_grads(loss_fn: Callable, params, worker_batches, *,
 
 
 def aggregate_reported(reported_grads, cfg: RobustConfig, *, key,
-                       shard_spec=None, staleness=None):
+                       shard_spec=None, staleness=None, info=None):
     """Robust aggregation of already-(possibly-)corrupted reports.
 
     Which config fields an aggregator receives is driven by its registry
@@ -131,6 +131,11 @@ def aggregate_reported(reported_grads, cfg: RobustConfig, *, key,
     exactly 0.0 past the bound) BEFORE the wire codec sees them — the
     server weighs what it has, then encodes/aggregates as usual.
 
+    ``info`` (a dict) is handed to the rule, which may record what it did
+    in it: the geometric-median rules store their Weiszfeld step count
+    under ``"weiszfeld_iters"``.  It is a side channel for the step's
+    metrics; nothing in it reaches the aggregate.
+
     This function is the Layer C trust boundary: ``reported_grads`` is
     ``report``-tainted (adversary-controlled end to end, including any
     wire payloads and codec scales derived from it downstream), and the
@@ -155,13 +160,15 @@ def aggregate_reported(reported_grads, cfg: RobustConfig, *, key,
                 raise ValueError(
                     f"compression {cfg.compression!r} needs a PRNG key")
             ckey = jax.random.fold_in(key, 29)
-        payload = codec.encode(reported_grads, key=ckey,
-                               shard_spec=shard_spec)
+        with jax.named_scope("encode"):
+            payload = codec.encode(reported_grads, key=ckey,
+                                   shard_spec=shard_spec)
         if agg.native_codec == cfg.compression:
             kwargs.update(like=reported_grads)
             reported_grads = payload
         else:
-            reported_grads = codec.decode(payload, reported_grads)
+            with jax.named_scope("decode"):
+                reported_grads = codec.decode(payload, reported_grads)
     if agg.needs_num_byzantine:
         kwargs.update(num_byzantine=cfg.num_byzantine)
     if agg.needs_key:
@@ -178,6 +185,8 @@ def aggregate_reported(reported_grads, cfg: RobustConfig, *, key,
                       round_backend=cfg.round_backend)
     if agg.needs_shard_spec and shard_spec is not None:
         kwargs.update(shard_spec=shard_spec)
+    if info is not None:
+        kwargs.update(info=info)
     return agg(reported_grads, **kwargs)
 
 
@@ -317,37 +326,44 @@ def make_run_rounds(loss_fn: Callable, optimizer, cfg: RobustConfig, *,
             else:
                 t, batch = xs, worker_batches
             key_t = jax.random.fold_in(key, t)
-            stacked, losses = per_worker_grads(loss_fn, params, batch,
-                                               loss_kwargs=loss_kwargs)
-            reported, mask, astate = schedule.apply(stacked, key_t, t, astate)
-            if arrival is None:
-                agg_grad = aggregate_reported(reported, cfg, key=key_t)
-            else:
-                from repro.core import staleness as staleness_lib
-                fresh = arrival.arrive(key_t, t, mask)
-                reported, stale_buffer = staleness_lib.merge_reports(
-                    stale_buffer, reported, fresh)
-                agg_grad = aggregate_reported(
-                    reported, cfg, key=key_t,
-                    staleness=(stale_buffer.age, stale_buffer.bound,
-                               cfg.staleness_discount))
-            updates, opt_state = optimizer.update(agg_grad, opt_state, params)
-            params = jax.tree.map(lambda p, u: (p + u).astype(p.dtype),
-                                  params, updates)
-            gnorm = jnp.sqrt(sum(
-                jnp.sum(jnp.square(g.astype(jnp.float32)))
-                for g in jax.tree.leaves(agg_grad)))
-            metrics = {
-                "loss_mean": jnp.mean(losses),
-                "loss_median": jnp.median(losses),
-                "agg_grad_norm": gnorm,
-                "byz_count": jnp.sum(mask.astype(jnp.int32)),
-            }
-            if arrival is not None:
-                metrics["stale_count"] = jnp.sum(
-                    (stale_buffer.age > 0).astype(jnp.int32))
-            if extra_metrics is not None:
-                metrics.update(extra_metrics(params, agg_grad))
+            with jax.named_scope("worker_grads"):
+                stacked, losses = per_worker_grads(loss_fn, params, batch,
+                                                   loss_kwargs=loss_kwargs)
+            with jax.named_scope("attack"):
+                reported, mask, astate = schedule.apply(stacked, key_t, t,
+                                                        astate)
+            with jax.named_scope("aggregate"):
+                if arrival is None:
+                    agg_grad = aggregate_reported(reported, cfg, key=key_t)
+                else:
+                    from repro.core import staleness as staleness_lib
+                    fresh = arrival.arrive(key_t, t, mask)
+                    reported, stale_buffer = staleness_lib.merge_reports(
+                        stale_buffer, reported, fresh)
+                    agg_grad = aggregate_reported(
+                        reported, cfg, key=key_t,
+                        staleness=(stale_buffer.age, stale_buffer.bound,
+                                   cfg.staleness_discount))
+            with jax.named_scope("optimizer"):
+                updates, opt_state = optimizer.update(agg_grad, opt_state,
+                                                      params)
+                params = jax.tree.map(lambda p, u: (p + u).astype(p.dtype),
+                                      params, updates)
+            with jax.named_scope("step_metrics"):
+                gnorm = jnp.sqrt(sum(
+                    jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in jax.tree.leaves(agg_grad)))
+                metrics = {
+                    "loss_mean": jnp.mean(losses),
+                    "loss_median": jnp.median(losses),
+                    "agg_grad_norm": gnorm,
+                    "byz_count": jnp.sum(mask.astype(jnp.int32)),
+                }
+                if arrival is not None:
+                    metrics["stale_count"] = jnp.sum(
+                        (stale_buffer.age > 0).astype(jnp.int32))
+                if extra_metrics is not None:
+                    metrics.update(extra_metrics(params, agg_grad))
             return (params, opt_state, astate, stale_buffer), metrics
 
         xs = (rounds, worker_batches) if per_round_batches else rounds
@@ -361,9 +377,9 @@ def make_run_rounds(loss_fn: Callable, optimizer, cfg: RobustConfig, *,
     jitted = jax.jit(_run, static_argnames=("num_rounds",
                                             "per_round_batches"))
 
-    def run(params, opt_state, worker_batches, key, *, num_rounds=None,
-            start_round=0, attack_state=None, stale_buffer=None,
-            per_round_batches=False):
+    def _args(params, opt_state, worker_batches, key, *, num_rounds=None,
+              start_round=0, attack_state=None, stale_buffer=None,
+              per_round_batches=False):
         if num_rounds is None:
             if not per_round_batches:
                 raise ValueError("num_rounds is required with a fixed batch")
@@ -371,10 +387,15 @@ def make_run_rounds(loss_fn: Callable, optimizer, cfg: RobustConfig, *,
         if isinstance(stale_buffer, tuple) and stale_buffer == ():
             # the disabled-path TrainState default — _run re-derives it
             stale_buffer = None
-        return jitted(params, opt_state, worker_batches, key, attack_state,
-                      stale_buffer, num_rounds, start_round,
-                      per_round_batches)
+        return (params, opt_state, worker_batches, key, attack_state,
+                stale_buffer, num_rounds, start_round, per_round_batches)
 
+    def run(*args, **kwargs):
+        return jitted(*_args(*args, **kwargs))
+
+    # ``run.lower(...)`` (same arguments) lowers the very program ``run``
+    # dispatches, e.g. to read its compiled HLO
+    run.lower = lambda *args, **kwargs: jitted.lower(*_args(*args, **kwargs))
     return run
 
 

@@ -386,7 +386,8 @@ def round_aggregate_pytree(stacked_grads, grouping: Grouping, *,
     Leaves are flattened and concatenated into one (m, D) f32 block (the
     geometric median is taken in the concatenated R^D, exactly like
     ``core.geometric_median_pytree``) and the result is split back, cast to
-    each leaf's dtype.  Compute is f32 throughout.
+    each leaf's dtype.  Compute is f32 throughout.  The round itself runs
+    under the ``round_kernel`` named scope.
     """
     leaves, treedef = jax.tree.flatten(stacked_grads)
     m = leaves[0].shape[0]
@@ -399,7 +400,8 @@ def round_aggregate_pytree(stacked_grads, grouping: Grouping, *,
                   tol=tol, eps=eps, tile_d=tile_d)
     if use_pallas or interpret:
         kwargs["interpret"] = interpret
-    y = fn(block, grouping, **kwargs)
+    with jax.named_scope("round_kernel"):
+        y = fn(block, grouping, **kwargs)
     out, offset = [], 0
     for l in leaves:
         size = int(np.prod(l.shape[1:], dtype=np.int64)) if l.ndim > 1 else 1

@@ -230,6 +230,13 @@ def make_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer, *,
     batch-group mean is its own "batch"), reproducing the historical
     trim + Weiszfeld tail value for value.
 
+    The step's layers run under ``jax.named_scope``s: ``group_fwd_bwd``,
+    ``attack``, ``aggregate`` (inside it the rule's own: ``encode`` /
+    ``decode``, ``batch_means``, ``trim``, ``weiszfeld``, ``round_kernel``),
+    ``optimizer`` and ``step_metrics``; they name the device ops in a
+    profile.  ``metrics["weiszfeld_iters"]`` (int32) is the reference
+    Weiszfeld loop's step count, 0 under rules that do not run it.
+
     ``schedule`` threads a multi-round ``AttackSchedule`` through the step
     (the pod-sweep path: attack × schedule at batch-mean granularity).
     When given, the step signature gains the adversary's carried state:
@@ -240,25 +247,34 @@ def make_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer, *,
     group_grads = make_group_grads(cfg, microbatches=microbatches)
 
     def _step_core(params, opt_state, batch, key, round_index, attack_state):
-        losses, grads = group_grads(params, batch)
-        if grad_shardings is not None:
-            grads = jax.lax.with_sharding_constraint(grads, grad_shardings)
-        if schedule is None:
-            reported, mask = report_groups(grads, rc, key, round_index)
-        else:
-            reported, mask, attack_state = schedule.apply(
-                grads, key, round_index, attack_state)
-        agg = aggregate_reported(reported, rc, key=key,
-                                 shard_spec=shard_spec)
-        updates, opt_state = optimizer.update(agg, opt_state, params)
-        params = jax.tree.map(lambda p, u: (p + u).astype(p.dtype),
-                              params, updates)
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                             for g in jax.tree.leaves(agg)))
-        metrics = {"loss_mean": jnp.mean(losses),
-                   "loss_median": jnp.median(losses),
-                   "agg_grad_norm": gnorm,
-                   "byz_count": jnp.sum(mask.astype(jnp.int32))}
+        with jax.named_scope("group_fwd_bwd"):
+            losses, grads = group_grads(params, batch)
+            if grad_shardings is not None:
+                grads = jax.lax.with_sharding_constraint(grads,
+                                                         grad_shardings)
+        with jax.named_scope("attack"):
+            if schedule is None:
+                reported, mask = report_groups(grads, rc, key, round_index)
+            else:
+                reported, mask, attack_state = schedule.apply(
+                    grads, key, round_index, attack_state)
+        info = {}
+        with jax.named_scope("aggregate"):
+            agg = aggregate_reported(reported, rc, key=key,
+                                     shard_spec=shard_spec, info=info)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(agg, opt_state, params)
+            params = jax.tree.map(lambda p, u: (p + u).astype(p.dtype),
+                                  params, updates)
+        with jax.named_scope("step_metrics"):
+            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                                 for g in jax.tree.leaves(agg)))
+            metrics = {"loss_mean": jnp.mean(losses),
+                       "loss_median": jnp.median(losses),
+                       "agg_grad_norm": gnorm,
+                       "byz_count": jnp.sum(mask.astype(jnp.int32)),
+                       "weiszfeld_iters": info.get(
+                           "weiszfeld_iters", jnp.zeros((), jnp.int32))}
         return params, opt_state, metrics, attack_state
 
     if schedule is None:

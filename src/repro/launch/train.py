@@ -303,7 +303,8 @@ def train_device(args) -> dict:
               "history": run["history"]}
     for i, (h, dt) in enumerate(zip(run["history"], run["step_seconds"])):
         print(f"[train] step {i} loss_mean={h['loss_mean']:.6f} "
-              f"agg_grad_norm={h['agg_grad_norm']:.6f} step_s={dt:.6f}")
+              f"agg_grad_norm={h['agg_grad_norm']:.6f} "
+              f"weiszfeld_iters={int(h['weiszfeld_iters'])} step_s={dt:.6f}")
     print(f"[train] {cfg.name} on {result['device']}: "
           f"compile_s={run['compile_seconds']:.3f} "
           f"peak_bytes_in_use={run['peak_bytes_in_use']}")

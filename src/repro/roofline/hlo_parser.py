@@ -239,3 +239,45 @@ def analyze(hlo_text: str, entry: str | None = None) -> HloCost:
     cost = HloCost()
     _walk(comps[entry], comps, 1.0, cost, False, {})
     return cost
+
+
+
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_HEADER_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w\.\-]+)\s")
+
+
+def _opcode(rhs: str) -> str:
+    """The opcode of an instruction's right-hand side: what follows its
+    result shape (a tuple shape is one balanced parenthesis group)."""
+    i = 0
+    if rhs.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rhs):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+    rest = rhs[i:].split(" ", 1)[1] if " " in rhs[i:] else ""
+    return rest.split("(", 1)[0]
+
+
+def op_names(hlo_text: str) -> list[tuple[str, str, str | None]]:
+    """``(instruction, opcode, op_name path or None)`` for every instruction
+    that runs as an op of its own: those of the entry computation and of
+    loop bodies and conditions, not the insides of fusions nor reducer or
+    comparator computations.  The path is the ``jax.named_scope`` stack
+    of the traced operation the instruction came from.  Reads CPU and TPU
+    compiled text alike (layouts, memory spaces)."""
+    inner = set(re.findall(r"(?:calls|to_apply)=%?([\w\.\-]+)", hlo_text))
+    out, comp = [], None
+    for line in hlo_text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            m = _HEADER_RE.match(line)
+            comp = m.group(1) if m else None
+            continue
+        im = _INSTR_RE.match(line)
+        if im is None or comp is None or comp in inner:
+            continue
+        m = _OP_NAME_RE.search(im.group(2))
+        out.append((im.group(1), _opcode(im.group(2)),
+                    m.group(1) if m else None))
+    return out
