@@ -44,8 +44,9 @@ Every rule honors the **shard-local contract** (see
 ``repro.core.shard_aggregation``): coordinate-wise rules touch each
 parameter shard independently (no cross-shard collectives at all), and the
 norm-based rules take an optional ``shard_spec`` so their distance/norm
-reductions combine per-shard partial squared norms — one (k,)-sized
-reduction per Weiszfeld iterate for GMoM, one (m, m) partial distance
+reductions combine per-shard partials — the (k,) trim norms and one
+(k, k) partial Gram matrix per geometric median for GMoM (the Weiszfeld
+loop then runs on it with no collective), one (m, m) partial distance
 reduction for krum.  A partitioned spec also forces the ``reference``
 round backend (the fused kernel's leaf concatenation would gather).
 
@@ -409,8 +410,9 @@ def gmom_aggregator(stacked_grads, *, num_batches: int | None = None,
     Pallas round kernel that keeps means+trim+Weiszfeld VMEM-resident.
     A partitioned ``shard_spec`` forces ``reference`` (the kernel would
     gather) and routes every distance/norm reduction through
-    :func:`repro.core.shard_aggregation.blocked_partial_sum` — one (k,)
-    reduction per Weiszfeld iterate, nothing of size d ever crosses shards.
+    :func:`repro.core.shard_aggregation.blocked_partial_sum` — the (k,)
+    trim norms and one (k, k) Gram reduction before the Weiszfeld loop,
+    nothing of size d ever crosses shards.
 
     ``info`` (a dict) receives the reference path's Weiszfeld step count
     under ``"weiszfeld_iters"``; the fused kernel keeps its loop counter in

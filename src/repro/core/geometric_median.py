@@ -4,14 +4,23 @@ The geometric median of ``{z_1..z_n}`` is ``argmin_y sum_i ||y - z_i||_2``
 (paper eq. (6)).  The paper invokes the [CLM+16] interior-point solver for a
 ``(1+gamma)``-approximation; that algorithm is sequential and CPU-bound with
 no TPU analogue, so we substitute the classical **Weiszfeld** fixed-point
-iteration (see DESIGN.md §3): each step is a batch of distance reductions and
-a weighted mean — exactly the VPU/MXU-friendly shape — and converges linearly
-to any required tolerance on non-collinear inputs.
+iteration (see DESIGN.md §3), which converges linearly to any required
+tolerance on non-collinear inputs.
+
+Two forms of the same iteration:
+
+* :func:`geometric_median` — the point form on an (n, d) f32 array: each
+  step reduces the n distances to the iterate and forms a weighted mean.
+* :func:`geometric_median_pytree` — the form every aggregator runs on the
+  stacked reports (a pytree with a leading k axis).  Every iterate is a
+  convex combination of the k points, so the loop iterates on the f32
+  coefficients over the k×k Gram matrix ``X Xᵀ``: one pass over the points
+  builds it, the loop touches nothing of size d, and one more pass forms
+  the median.  Under the shard-local contract the Gram matrix is the only
+  cross-shard reduction.
 
 All entry points are pure-functional and jit/pjit friendly (``lax.while_loop``
-/ ``lax.fori_loop`` only, no Python control flow on traced values).  Points
-may live on a sharded mesh: every reduction is a plain ``jnp`` reduction so
-GSPMD inserts the cross-device psums.
+/ ``lax.fori_loop`` only, no Python control flow on traced values).
 
 Supports optional per-point weights so that norm-trimmed points (paper
 Remark 2) participate with weight zero without changing static shapes.
@@ -29,6 +38,12 @@ import jax.numpy as jnp
 # bit-equality contract (tests/test_shardmap_aggregate.py): reductions over
 # the stacked k/member axis must stay unrolled multiply-add chains
 # (_wsum) or route through blocked_partial_sum (repro.verify RV101/RV105).
+
+
+#: the largest k whose Gram matrix is built as k(k+1)/2 fused scalar
+#: reductions; above it, as one dot_general (the crossover on a TPU v5e
+#: lies between k = 8 and k = 12, see ``geometric_median_pytree``)
+GRAM_PAIRS_MAX_K = 8
 
 
 class WeiszfeldState(NamedTuple):
@@ -100,6 +115,22 @@ def geometric_median(points: jax.Array,
     return final.y
 
 
+def weighted_sum(w: jax.Array, l: jax.Array) -> jax.Array:
+    """``Σ_i w_i l[i]`` over the leading k axis of ``l``, in ``l``'s dtype."""
+    # an UNROLLED elementwise multiply-add chain: each output coordinate gets a fixed expression
+    # tree, so a shard's slice computes exactly the bits of the full
+    # leaf's slice.  Both a dot/tensordot lowering and a fused
+    # broadcast-multiply + sum-over-k are width-sensitive (the compiler
+    # may reassociate or vectorize the k-reduction differently per
+    # coordinate width), which would break the shard-local bit-equality
+    # contract; k is small (<= num_workers) so unrolling is cheap.
+    wf = w.astype(l.dtype)
+    acc = wf[0] * l[0]
+    for i in range(1, l.shape[0]):
+        acc = acc + wf[i] * l[i]
+    return acc
+
+
 def geometric_median_pytree(batch_means, *,
                             weights: jax.Array | None = None,
                             max_iters: int = 64,
@@ -111,18 +142,30 @@ def geometric_median_pytree(batch_means, *,
 
     ``batch_means`` is a pytree whose leaves have a leading axis k (the batch
     means, stacked).  The geometric median treats the concatenation of all
-    leaves as one R^d vector: distances are summed across leaves via plain
-    jnp reductions (=> psum across the model axis when leaves are sharded);
-    **no leaf is ever gathered or flattened**, so the peak memory per device
-    stays at k × (its shard of the model).
+    leaves as one R^d vector; **no leaf is ever gathered, flattened or copied
+    to f32**, so the peak memory per device stays at k × (its shard of the
+    model).
+
+    Every Weiszfeld iterate is a convex combination ``y = Σ_j c_j x_j`` of
+    the k points (the start is the weighted mean, and each update
+    reweights the points), so the loop runs on the f32 coefficients ``c``
+    over the k×k Gram matrix ``G = X Xᵀ`` instead of on a d-sized iterate:
+
+    * ``gram``    — one pass over the points builds ``G`` (f32 accumulation
+      of the points' products, per leaf);
+    * the loop    — ``‖x_i − y‖² = G_ii − 2(Gc)_i + cᵀGc`` and the movement
+      ``‖y' − y‖² = (c' − c)ᵀ G (c' − c)``, both on k×k numbers;
+    * ``combine`` — one more pass forms ``y = Σ_j c_j x_j`` in the points'
+      dtype.
+
+    The stopping rule (squared movement <= ``tol²`` or ``max_iters``) and
+    the ``eps`` smoothing are the point form's (:func:`geometric_median`).
 
     ``shard_spec`` (a :class:`repro.core.shard_aggregation.ShardSpec`)
-    selects the shard-local contract: the Weiszfeld iterate and every
-    weighted mean stay per-shard (the weighted k-sums are coordinate-local
-    and bitwise width-invariant), and only the (k,) squared distances and
-    the scalar movement cross shards — ONE small blocked reduction per
-    iterate.  With a trivial spec (None / gspmd) the reductions follow the
-    legacy accumulation order (golden traces stay within tolerance).
+    selects the shard-local contract: ``G`` is accumulated from per-shard
+    partials through ONE (k, k) blocked reduction, the only collective;
+    the loop runs on the replicated ``G`` and the combine is
+    coordinate-local (bitwise width-invariant).
 
     ``info`` (a dict) receives ``"weiszfeld_iters"``: the loop's final
     counter, the Weiszfeld steps taken (int32, at most ``max_iters``).
@@ -138,63 +181,69 @@ def geometric_median_pytree(batch_means, *,
     weights = weights.astype(jnp.float32)
     w_sum = jnp.maximum(jnp.sum(weights), eps)
 
-    def _wsum(w, l):
-        # weighted sum over the leading k axis as an UNROLLED elementwise
-        # multiply-add chain: each output coordinate gets a fixed expression
-        # tree, so a shard's slice computes exactly the bits of the full
-        # leaf's slice.  Both a dot/tensordot lowering and a fused
-        # broadcast-multiply + sum-over-k are width-sensitive (the compiler
-        # may reassociate or vectorize the k-reduction differently per
-        # coordinate width), which would break the shard-local bit-equality
-        # contract; k is small (<= num_workers) so unrolling is cheap.
-        wf = w.astype(l.dtype)
-        acc = wf[0] * l[0]
-        for i in range(1, l.shape[0]):
-            acc = acc + wf[i] * l[i]
+    def _leaf_gram(l):
+        # X Xᵀ over every coordinate dim, from exact products of the points
+        # accumulated in f32, reading each leaf once
+        if k <= GRAM_PAIRS_MAX_K:
+            # one scalar multiply-reduce per pair: XLA fuses the siblings
+            # into one pass over the leaf.  The TPU lowers the dot below as
+            # a convolution: on a v5e, one (k, 4096, 14336) bf16 leaf takes
+            # 1.2 ms this way against the dot's 3.3 ms at k = 4, 3.2 against
+            # 3.6 ms at k = 8, and 9.4 against 7.2 ms at k = 12 (PERF.md)
+            g = [[None] * k for _ in range(k)]
+            for i in range(k):
+                for j in range(i, k):
+                    g[i][j] = g[j][i] = jnp.sum(l[i].astype(jnp.float32)
+                                                * l[j].astype(jnp.float32))
+            return jnp.stack([jnp.stack(row) for row in g])
+        # past it the k(k+1)/2 reductions cost more than one matrix-unit
+        # contraction (HIGHEST keeps f32 points at f32 on the TPU)
+        axes = tuple(range(1, l.ndim))
+        return jax.lax.dot_general(
+            l, l, dimension_numbers=((axes, axes), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+
+    with jax.named_scope("gram"):
+        gram = blocked_partial_sum(shard_spec, leaves, _leaf_gram,
+                                   shape=(k, k), lead_axes=1)
+    diag = jnp.diagonal(gram)
+
+    # the loop's k-reductions as unrolled f32 multiply-add chains: exact f32
+    # on every backend (no matrix unit), and a fixed expression tree
+    def _gram_times(c):
+        acc = gram[:, 0] * c[0]
+        for j in range(1, k):
+            acc = acc + gram[:, j] * c[j]
         return acc
 
-    def wmean(ls):
-        return [_wsum(weights, l) / w_sum.astype(l.dtype) for l in ls]
-
-    def _pair_sq(l, yl):
-        diff = (l - yl[None]).astype(jnp.float32)
-        return jnp.sum(diff * diff, axis=tuple(range(1, diff.ndim)))
-
-    def sq_dists(ls, y):
-        """(k,) squared distances from stacked points to estimate y."""
-        return blocked_partial_sum(shard_spec, list(zip(ls, y)), _pair_sq,
-                                   shape=(k,), lead_axes=1)
-
-    def step(y):
-        d = jnp.sqrt(sq_dists(leaves, y) + eps * eps)        # (k,)
-        inv = weights / d
-        denom = jnp.maximum(jnp.sum(inv), eps)
-        y_new = [_wsum(inv / denom, l) for l in leaves]
-        return y_new
-
-    y0 = wmean(leaves)
-
-    def _pair_delta(x, z):
-        return jnp.sum((x - z).astype(jnp.float32) ** 2)
-
-    def flat_delta(a, b):
-        return blocked_partial_sum(shard_spec, list(zip(a, b)), _pair_delta,
-                                   shape=(), lead_axes=0)
+    def _inner(a, b):
+        acc = a[0] * b[0]
+        for j in range(1, k):
+            acc = acc + a[j] * b[j]
+        return acc
 
     def cond(carry):
         _, it, delta = carry
         return jnp.logical_and(it < max_iters, delta > tol * tol)
 
     def body(carry):
-        y, it, _ = carry
-        y_new = step(y)
-        return (y_new, it + 1, flat_delta(y_new, y))
+        c, it, _ = carry
+        gc = _gram_times(c)
+        sq = jnp.maximum(diag - 2.0 * gc + _inner(c, gc), 0.0)
+        inv = weights / jnp.sqrt(sq + eps * eps)
+        c_new = inv / jnp.maximum(jnp.sum(inv), eps)
+        dc = c_new - c
+        return (c_new, it + 1,
+                jnp.maximum(_inner(dc, _gram_times(dc)), 0.0))
 
-    y, it, _ = jax.lax.while_loop(
-        cond, body, (y0, jnp.zeros((), jnp.int32),
+    c, it, _ = jax.lax.while_loop(
+        cond, body, (weights / w_sum, jnp.zeros((), jnp.int32),
                      jnp.array(jnp.inf, jnp.float32)))
     if info is not None:
         info["weiszfeld_iters"] = it
+    with jax.named_scope("combine"):
+        y = [weighted_sum(c, l) for l in leaves]
     return jax.tree.unflatten(treedef, y)
 
 

@@ -14,9 +14,10 @@ operate on per-shard slices:
 * **norm-based rules** (``gmom``, ``geomed``, ``gmom_per_leaf``,
   ``norm_select``, ``norm_clip_mean``, ``norm_filter_gmom``, ``krum``)
   need only *scalar-sized* cross-shard reductions: per-shard partial
-  squared norms combined into the (k,) distance/norm vectors (one such
-  reduction per Weiszfeld iterate for GMoM) and one (m, m) partial
-  distance reduction for krum.
+  squared norms combined into the (k,) norm vectors, one (k, k) partial
+  Gram matrix per geometric median for GMoM (its Weiszfeld loop runs on
+  it with no collective), and one (m, m) partial distance reduction for
+  krum.
 
 :class:`ShardSpec` describes how the stacked gradients are partitioned and
 which execution mode combines the partials:

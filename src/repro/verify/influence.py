@@ -36,7 +36,10 @@ documented imprecisions):
 * Composite sanitizers that are invisible at single-primitive granularity
   (the Weiszfeld ``1/dist`` reweighting inside a ``while`` loop) are
   recognized structurally by a flag-propagation pass over the loop body —
-  still with zero name-based special cases.
+  still with zero name-based special cases.  A loop that fires and also
+  carries the Weiszfeld *coefficients* (the Gram form) marks them
+  ``coef``; the combine that multiplies them into the raw reports after
+  the loop is what demotes.
 """
 
 from __future__ import annotations
@@ -61,6 +64,10 @@ class Label:
     level: int = CLEAN
     kinds: frozenset = frozenset()
     sources: frozenset = frozenset()
+    #: a Weiszfeld coefficient vector: the normalized inverse-distance
+    #: weights carried out of a loop the detector fired on, or a slice,
+    #: cast or reshape of them (see ``_COEF_PRIMS``)
+    coef: bool = False
 
     def join(self, other: "Label") -> "Label":
         if other is CLEAN_LABEL:
@@ -69,7 +76,8 @@ class Label:
             return other
         return Label(level=max(self.level, other.level),
                      kinds=self.kinds | other.kinds,
-                     sources=self.sources | other.sources)
+                     sources=self.sources | other.sources,
+                     coef=self.coef and other.coef)
 
     def cap_bounded(self) -> "Label":
         """Influence through a comparison / index-valued op: the value
@@ -88,6 +96,8 @@ class Label:
 
     def describe(self) -> str:
         parts = [_LEVEL_NAMES[self.level]]
+        if self.coef:
+            parts.append("coef")
         if self.kinds:
             parts.append("kinds={" + ",".join(sorted(self.kinds)) + "}")
         if self.sources:
@@ -122,6 +132,11 @@ _CAP_PRIMS = {"lt", "gt", "le", "ge", "eq", "ne", "argmin", "argmax",
 # value-selection by index; dynamic_update_slice is deliberately absent
 # (its update operand embeds a raw VALUE — default join applies).
 _GATHER_PRIMS = {"gather", "dynamic_slice"}
+
+# shape-only ops a coefficient vector keeps its ``coef`` mark through
+# (``weighted_sum``'s ``w.astype(dtype)[i]``); any other op drops it
+_COEF_PRIMS = {"convert_element_type", "slice", "squeeze", "reshape",
+               "broadcast_in_dim"}
 
 # higher-order call-like primitives: the sub-jaxpr binds eqn.invars
 # positionally (jaxpr param key varies by primitive / jax version).
@@ -193,9 +208,21 @@ def _transfer(name: str, eqn, ins: list[Label]) -> Label:
                      kinds=operand.kinds | idx.kinds
                            | frozenset({"rank_select"}),
                      sources=operand.sources | idx.sources)
+    if name in ("mul", "dot_general") and len(ins) == 2:
+        a, b = ins
+        if (a.coef and b.level == RAW) or (b.coef and a.level == RAW):
+            # the Gram form's combine y = Σ c_j x_j: Weiszfeld coefficients
+            # (c_j ∝ w_j / ‖x_j − y‖) into the raw reports — the same
+            # reweighted report sum the point form makes inside its loop
+            return Label(level=BOUNDED,
+                         kinds=a.kinds | b.kinds | frozenset({"weiszfeld"}),
+                         sources=a.sources | b.sources)
     # default: join.  Sums, means, muls, dots, scatters, bitwise ops,
     # conversions, broadcasts — none of them bound per-worker influence.
-    return join_all(ins)
+    out = join_all(ins)
+    if out.coef and name not in _COEF_PRIMS:
+        out = dataclasses.replace(out, coef=False)
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -312,10 +339,14 @@ def _while(eqn, ins: list[Label]) -> list[Label]:
         if new == carry:
             break
         carry = new
-    if any(l.level == RAW for l in carry) and \
-            _weiszfeld_fires(body, body_consts + carry, bn):
-        carry = [l.demote("weiszfeld") if l.level == RAW else l
-                 for l in carry]
+    if any(l.level == RAW for l in carry):
+        flags = _weiszfeld_flags(body, body_consts + carry, bn)
+        if any("wprod" in f for f in flags):
+            carry = [dataclasses.replace(l.demote("weiszfeld"),
+                                         coef="wprod" not in f
+                                         and "inv_w" in f)
+                     if l.level == RAW else l
+                     for l, f in zip(carry, flags)]
     return carry
 
 
@@ -361,16 +392,26 @@ def _cond(eqn, ins: list[Label]) -> list[Label]:
 #   inv_w ⊙ raw_points  (mul or dot)  (the reweighted report sum)
 #   … reaching a carry output of the while body.
 #
+# In a loop that fires, a carry that inv_w reaches with no such product
+# on its way is marked ``coef`` for the ``mul``/``dot_general`` rule of
+# ``_transfer``: the Gram form carries the coefficients c over X Xᵀ and
+# forms y = Σ c_j x_j after the loop, while its movement (c' − c)ᵀG(c' − c)
+# is the product that fires.  A loop with no product never fires, so its
+# 1/d carries get no mark and a raw carry beside them stays RAW.
+#
 # Flags union-propagate forward; sub-jaxpr-bearing eqns inside the body
 # propagate conservatively (flags joined across the call, no descent).
 
-def _weiszfeld_fires(body, in_labels: list[Label], nconsts: int) -> bool:
+def _weiszfeld_flags(body, in_labels: list[Label],
+                     nconsts: int) -> list[frozenset]:
+    """The detector's flags on each carry output of a ``while`` body;
+    none where the body cannot be analysed."""
     jaxpr = _closed_parts(body)
     labels: dict[Any, Label] = {}
     try:
         run_jaxpr(jaxpr, in_labels, capture=labels)
     except ValueError:
-        return False
+        return [frozenset()] * len(jaxpr.outvars)
 
     def lab(v) -> Label:
         if _is_literal(v):
@@ -413,4 +454,4 @@ def _weiszfeld_fires(body, in_labels: list[Label], nconsts: int) -> bool:
         for v in eqn.outvars:
             flags[v] = out
 
-    return any("wprod" in fl(v) for v in jaxpr.outvars)
+    return [fl(v) for v in jaxpr.outvars]

@@ -178,3 +178,83 @@ def test_jit_and_grad_safe():
     g = jax.jit(lambda p: geometric_median(p))(pts)
     assert g.shape == (4,)
     assert bool(jnp.all(jnp.isfinite(g)))
+
+
+def _float64_weiszfeld(z, w, *, max_iters, tol, eps=1e-12):
+    """The point-form Weiszfeld in float64 numpy on (k, d) points, with the
+    pytree form's start, smoothing and stopping rule."""
+    y, it, delta = w @ z / max(w.sum(), eps), 0, np.inf
+    while it < max_iters and delta > tol * tol:
+        inv = w / np.sqrt(((z - y) ** 2).sum(axis=1) + eps * eps)
+        y_new = (inv / max(inv.sum(), eps)) @ z
+        y, it, delta = y_new, it + 1, ((y_new - y) ** 2).sum()
+    return y
+
+
+def _gram_case(name):
+    """``(leaves, weights or None, tolerance)`` of one case: the tolerance
+    on the largest coordinate gap, as a share of the largest coordinate
+    (f32 rounding, more where the distances cancel; bf16 leaves come back
+    in bf16, formed by a bf16 multiply-add chain)."""
+    rng = np.random.default_rng(7)
+    if name == "random":
+        z = rng.normal(size=(7, 10)) * 10
+        return [z[:, :4], z[:, 4:].reshape(7, 3, 2)], None, 1e-6
+    if name == "trimmed_sign_flip":
+        z = rng.normal(size=(5, 12)) + 3.0
+        z[4] = -10.0 * z[0]
+        return [z[:, :5], z[:, 5:]], "trim", 1e-6
+    if name == "near_coincident":
+        # three reports 1e-4 apart around a far offset: the median lies on
+        # top of them, where G_ii - 2(Gc)_i + cᵀGc cancels
+        base = rng.normal(size=12) * 100
+        z = base + rng.normal(size=(5, 12))
+        z[:3] = base + 1e-4 * rng.normal(size=(3, 12))
+        return [z[:, :7], z[:, 7:]], None, 1e-5
+    if name == "bf16_leaves":
+        z = rng.normal(size=(6, 3 * 5 + 7 + 8)) + 1.0
+        return ([z[:, :15].reshape(6, 3, 5), z[:, 15:22],
+                 z[:, 22:].reshape(6, 2, 2, 2)], "bf16", 2 ** -7)
+    if name == "k1":
+        return [rng.normal(size=(1, 9))], None, 1e-6
+    if name == "k2":
+        z = rng.normal(size=(2, 9))
+        return [z[:, :4], z[:, 4:]], None, 1e-6
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize("case", ["random", "trimmed_sign_flip",
+                                  "near_coincident", "bf16_leaves", "k1",
+                                  "k2"])
+def test_gram_form_matches_point_form(case):
+    """The pytree Weiszfeld, iterating on coefficients over X Xᵀ, lands on
+    the point form's median: the f32 flat ``geometric_median`` and a
+    float64 numpy Weiszfeld with the same start and stopping rule."""
+    leaves, weights, tol = _gram_case(case)
+    dtype = jnp.bfloat16 if weights == "bf16" else jnp.float32
+    tree = [jnp.asarray(l, dtype) for l in leaves]
+    k = leaves[0].shape[0]
+    z = np.concatenate([np.asarray(l, np.float64).reshape(k, -1)
+                        for l in tree], axis=1)
+    w = None
+    if weights == "trim":
+        w = trim_weights(batch_mean_norms(tree), multiplier=3.0)
+        assert float(w[-1]) == 0.0 and float(jnp.sum(w)) == k - 1
+    # stop where f32 coefficients stop moving the point: a millionth of
+    # the largest report's norm
+    move = 1e-6 * float(np.max(np.linalg.norm(z, axis=1)))
+    info = {}
+    got = geometric_median_pytree(tree, weights=w, max_iters=200, tol=move,
+                                  info=info)
+    assert [g.dtype for g in got] == [dtype] * len(tree)
+    got = np.concatenate([np.asarray(g, np.float64).reshape(-1)
+                          for g in got])
+    w64 = np.ones(k) if w is None else np.asarray(w, np.float64)
+    want64 = _float64_weiszfeld(z, w64, max_iters=200, tol=move)
+    flat = np.asarray(geometric_median(jnp.asarray(z, jnp.float32),
+                                       weights=w, max_iters=200, tol=move),
+                      np.float64)
+    scale = np.max(np.abs(z))
+    assert np.max(np.abs(got - want64)) <= tol * scale
+    assert np.max(np.abs(got - flat)) <= tol * scale
+    assert 1 <= int(info["weiszfeld_iters"]) < 200

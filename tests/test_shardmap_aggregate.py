@@ -195,3 +195,80 @@ def test_every_aggregator_sharded_vs_gathered_bit_identical():
         env=dict(os.environ, PYTHONPATH=os.path.join(REPO, "src")))
     assert res.returncode == 0, (res.stdout[-800:], res.stderr[-4000:])
     assert "OK" in res.stdout
+
+
+# ---------------------------------------------------------------------------
+# the Weiszfeld loop's collectives under the shard-local contract: the loop
+# runs on the k×k Gram matrix, so the only cross-shard reduction of the
+# geometric median is the one (k, k) partial-Gram combine before the loop.
+
+GRAM_COLLECTIVES_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import re
+    import jax, jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from repro.core import RobustConfig, make_sharded_aggregate
+    from repro.launch.mesh import make_mesh
+    from repro.roofline.hlo_parser import parse_computations
+
+    m, k, S = 8, 4, 8
+    mesh = make_mesh((S,), ("model",))
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    stacked = {"w": jax.random.normal(ks[0], (m, 16), jnp.float32),
+               "b": jax.random.normal(ks[1], (m, 4, 8), jnp.float32)}
+    cfg = RobustConfig(num_workers=m, num_byzantine=1, num_batches=k,
+                       attack="none", aggregator="gmom",
+                       gmom_max_iters=32, gmom_tol=1e-7)
+    spec = lambda x: P(*((None,) * (x.ndim - 1) + ("model",)))
+    fn = jax.shard_map(make_sharded_aggregate(cfg, mesh), mesh=mesh,
+                       in_specs=(jax.tree.map(spec, stacked), P(None)),
+                       out_specs={"w": P("model"), "b": P(None, "model")},
+                       check_vma=False)
+    text = jax.jit(fn).lower(stacked, jax.random.PRNGKey(0)).compile() \\
+        .as_text()
+    comps = parse_computations(text)
+    COLL = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+            "collective-permute")
+
+    def reach(name, seen):
+        if name in seen or name not in comps:
+            return seen
+        seen.add(name)
+        for ins in comps[name].instrs:
+            for callee in re.findall(
+                    r"(?:calls|body|condition|to_apply)=%?([\\w.\\-]+)",
+                    ins.rest):
+                reach(callee, seen)
+        return seen
+
+    whiles = [ins for c in comps.values() for ins in c.instrs
+              if ins.op == "while"]
+    assert len(whiles) == 1, [w.name for w in whiles]
+    inside = set()
+    for callee in re.findall(r"(?:body|condition)=%?([\\w.\\-]+)",
+                             whiles[0].rest):
+        reach(callee, inside)
+    assert inside
+    colls = [(c.name, ins) for c in comps.values() for ins in c.instrs
+             if ins.op.startswith(COLL)]
+    in_loop = [ins.name for c, ins in colls if c in inside]
+    assert in_loop == [], in_loop
+    gram = [ins.name for _, ins in colls
+            if f"f32[{S},{k},{k}]" in ins.result_text]
+    assert len(gram) == 1, [ins.result_text for _, ins in colls]
+    print("OK", len(colls))
+""")
+
+
+def test_blocked_gmom_has_one_gram_reduction_and_none_in_the_loop():
+    """Under a blocked ShardSpec the compiled gmom aggregation holds no
+    collective inside the Weiszfeld ``while`` and exactly one (k, k)
+    partial-Gram all-gather before it (the trim's (k,) norms are the only
+    other collective)."""
+    res = subprocess.run(
+        [sys.executable, "-c", GRAM_COLLECTIVES_SCRIPT],
+        capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, PYTHONPATH=os.path.join(REPO, "src")))
+    assert res.returncode == 0, (res.stdout[-800:], res.stderr[-4000:])
+    assert "OK" in res.stdout

@@ -6,6 +6,8 @@ instruction of the compiled module, which is how a profile of the step is
 split by layer.  The step's ``weiszfeld_iters`` output is the reference
 geometric median's final loop counter."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,11 +23,15 @@ from repro.models import model as model_lib
 from repro.roofline.hlo_parser import op_names
 
 STEP_SCOPES = ("group_fwd_bwd", "attack", "aggregate", "optimizer",
-               "step_metrics", "batch_means", "trim", "weiszfeld")
+               "step_metrics", "batch_means", "trim", "weiszfeld", "gram")
 ROUND_SCOPES = ("worker_grads", "attack", "aggregate", "optimizer",
-                "step_metrics", "batch_means", "trim", "weiszfeld")
-ALL_SCOPES = set(STEP_SCOPES + ROUND_SCOPES) | {"encode", "decode",
-                                                "round_kernel"}
+                "step_metrics", "batch_means", "trim", "weiszfeld", "gram")
+# scopes whose ops are elementwise, so that the compiler may fuse them into
+# their consumers and name no op of its own after them: the Weiszfeld
+# combine's multiply-add chain (into the step's metrics and update)
+FUSED_SCOPES = ("combine",)
+ALL_SCOPES = set(STEP_SCOPES + ROUND_SCOPES + FUSED_SCOPES) | {
+    "encode", "decode", "round_kernel"}
 # what the CPU compiler leaves outside every scope: the arguments, and
 # instructions it makes itself, without an op_name of a traced operation
 COMPILER_MADE = {"parameter", "constant", "tuple", "get-tuple-element",
@@ -55,13 +61,18 @@ def _compiled_step(cfg, rc, stream):
                         jnp.int32(0)).compile()
 
 
-def scope_problems(hlo_text, expected):
+def scope_problems(hlo_text, expected, fused=()):
     """``(scopes missing, traced instructions under no scope, unscoped
-    instructions of other kinds)`` of a compiled module's text."""
+    instructions of other kinds)`` of a compiled module's text.  A scope of
+    ``expected`` is missing without an op of its own under it; one of
+    ``fused`` without a traced operation under it, fused or not."""
     rows = op_names(hlo_text)
     seen = {part for _, _, path in rows if path
             for part in path.split("/")}
-    missing = [s for s in expected if s not in seen]
+    traced_ops = {part for path in re.findall(r'op_name="([^"]*)"', hlo_text)
+                  for part in path.split("/")}
+    missing = [s for s in expected if s not in seen] + [
+        s for s in fused if s not in traced_ops]
     traced, other = [], []
     for name, opcode, path in rows:
         if path and ALL_SCOPES & set(path.split("/")):
@@ -80,10 +91,23 @@ def gmom_step_text():
 
 
 def test_every_layer_of_the_group_step_is_scoped(gmom_step_text):
-    missing, traced, other = scope_problems(gmom_step_text, STEP_SCOPES)
+    missing, traced, other = scope_problems(gmom_step_text, STEP_SCOPES,
+                                            FUSED_SCOPES)
     assert missing == []
     assert traced == []
     assert other == []
+
+
+@pytest.mark.parametrize("scope", ["gram", "combine"])
+def test_weiszfeld_passes_nest_under_weiszfeld(gmom_step_text, scope):
+    """The Gram and combine passes are parts of the ``weiszfeld`` layer: every
+    traced operation of theirs, fused or not, carries ``weiszfeld/<scope>``."""
+    paths = [p.split("/") for p in re.findall(r'op_name="([^"]*)"',
+                                              gmom_step_text)
+             if scope in p.split("/")]
+    assert paths
+    for parts in paths:
+        assert parts[parts.index(scope) - 1] == "weiszfeld", parts
 
 
 @pytest.mark.parametrize("renamed, outermost", [("optimizer", True),
@@ -101,9 +125,17 @@ def test_a_dropped_or_renamed_scope_is_caught(gmom_step_text, renamed,
 
 def test_wire_codec_is_scoped():
     text = _compiled_step(*_tiny(compression="int8_stochastic")).as_text()
+    # the elementwise decode fuses into its readers: the trim norms, the
+    # Gram pass and the combine
     missing, traced, other = scope_problems(
-        text, ("encode", "decode", "aggregate", "weiszfeld"))
+        text, ("encode", "aggregate", "weiszfeld"), fused=("decode",))
     assert missing == [] and traced == [] and other == []
+    # with no op of its own, decode is timed only inside its readers: each
+    # of its traced operations must still sit directly under ``aggregate``
+    for path in re.findall(r'op_name="([^"]*)"', text):
+        parts = path.split("/")
+        if "decode" in parts:
+            assert parts[parts.index("decode") - 1] == "aggregate", parts
 
 
 def _linreg_runner(round_backend):
@@ -124,7 +156,8 @@ def _linreg_runner(round_backend):
 def test_round_runner_is_scoped_and_lowers_what_it_runs():
     run, args = _linreg_runner("reference")
     compiled = run.lower(*args, num_rounds=3).compile()
-    assert scope_problems(compiled.as_text(), ROUND_SCOPES)[0] == []
+    assert scope_problems(compiled.as_text(), ROUND_SCOPES,
+                          FUSED_SCOPES)[0] == []
     np.testing.assert_array_equal(
         np.asarray(compiled(*args, None, None, 0)[0]),
         np.asarray(run(*args, num_rounds=3)[0]))

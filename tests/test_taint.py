@@ -240,6 +240,85 @@ def test_leaky_dummy_rejected_rv301_unsharded():
         contracts.clear_trace_cache()
 
 
+def test_gram_form_weiszfeld_demotes_at_the_combine():
+    """The Gram-form geometric median carries coefficients out of its loop
+    (marked ``coef``); their combine with the raw reports is what certifies
+    BOUNDED with the ``weiszfeld`` kind."""
+    from repro.core import geometric_median_pytree
+    x = jnp.zeros((5, 6))
+    (out,) = labels_of(lambda g: geometric_median_pytree([g])[0],
+                       [RAW_REPORT], x)
+    assert out.level == influence.BOUNDED and not out.coef
+    assert out.kinds == {"weiszfeld"} and out.sources == {"report"}
+
+
+def _inverse_norm_coefficients(g):
+    inv = 1.0 / jnp.sqrt(jnp.sum(jnp.square(g), axis=1) + 1e-24)
+    return inv / jnp.sum(inv)
+
+
+def _coefficients_from_another_loop(g):
+    # a loop over the same (k,) coefficients that never takes a distance
+    # to its carried point: no sqrt -> 1/d chain, so the detector is silent
+    norms = jnp.sum(jnp.square(g), axis=1)
+
+    def body(carry):
+        i, c = carry
+        w = c * norms
+        return i + 1, w / jnp.sum(w)
+
+    return jax.lax.while_loop(lambda carry: carry[0] < 4, body,
+                              (0, jnp.full((g.shape[0],), 0.2)))[1]
+
+
+@pytest.mark.parametrize("coefficients", [_inverse_norm_coefficients,
+                                          _coefficients_from_another_loop])
+def test_combine_without_a_weiszfeld_loop_stays_raw(coefficients):
+    """The same combine, with coefficients that no firing Weiszfeld loop
+    carried out, launders nothing."""
+    from repro.core.geometric_median import weighted_sum
+    x = jnp.zeros((5, 6))
+    (out,) = labels_of(lambda g: weighted_sum(coefficients(g), g),
+                       [RAW_REPORT], x)
+    assert out.level == influence.RAW and "weiszfeld" not in out.kinds
+
+
+def test_inverse_distance_carry_beside_a_raw_sum_stays_raw():
+    """A loop that carries normalized 1/d weights next to a raw running sum,
+    with no product of the weights and the reports, is no Weiszfeld loop:
+    the sum stays RAW and the weights get no ``coef`` mark."""
+    x = jnp.zeros((5, 6))
+
+    def fn(g):
+        def body(carry):
+            i, c, acc = carry
+            inv = 1.0 / jnp.sqrt(jnp.sum(jnp.square(g - acc), axis=1) + 1e-24)
+            return i + 1, inv / jnp.sum(inv), acc + g[0]
+
+        _, c, acc = jax.lax.while_loop(
+            lambda carry: carry[0] < 4, body,
+            (0, jnp.full((g.shape[0],), 0.2), jnp.zeros(g.shape[1:])))
+        return c, acc
+
+    c, acc = labels_of(fn, [RAW_REPORT], x)
+    assert acc.level == influence.RAW and "weiszfeld" not in acc.kinds
+    assert c.level == influence.RAW and not c.coef
+
+
+def test_coef_combine_dummy_certifies_raw():
+    """The registry form of the precision check above: a registered rule
+    whose combine uses closed-form inverse-distance coefficients."""
+    from repro.verify import contracts, taint
+    mod = _load_fixture("coef_combine")
+    try:
+        rep = taint.classify_aggregator(mod.NAME)
+        assert rep.level == influence.RAW, rep
+        assert "weiszfeld" not in rep.kinds
+    finally:
+        mod.unregister()
+        contracts.clear_trace_cache()
+
+
 def test_clean_clip_zero_false_positives():
     """Precision: a dummy that READS tainted values everywhere (median
     norm envelope, coordinate-median base) but only inside bounded ops
