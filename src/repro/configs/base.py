@@ -36,6 +36,23 @@ class ModelConfig:
     num_experts: int = 0
     experts_per_token: int = 0
     moe_capacity_factor: float = 1.25
+    # --- DeepSeek-V3 expert layer (moe_router == "sigmoid"): sigmoid scores
+    # with a selection bias, top-k weights normalised then scaled, shared
+    # experts, a sequence-wise balance loss and dropless dispatch over the
+    # experts this chip holds (models/moe.py); d_ff is one routed expert ---
+    moe_router: str = "softmax"    # softmax (capacity path) | sigmoid
+    num_shared_experts: int = 0
+    routed_scaling: float = 1.0
+    balance_alpha: float = 0.0
+    experts_held: int = 0          # 0 => all num_experts
+    experts_held_lo: int = 0       # the first held expert's index
+    first_dense_layers: int = 0    # leading layers with a dense SwiGLU ...
+    dense_d_ff: int = 0            # ... of this width
+    # --- multi-head latent attention (kv_lora_rank > 0; models/attention.py)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- SSM (rwkv / mamba) ---
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -80,6 +97,13 @@ class ModelConfig:
             vocab_size=min(self.vocab_size, 512),
             num_experts=min(self.num_experts, 4),
             experts_per_token=min(self.experts_per_token, 2),
+            experts_held=min(self.experts_held, 4),
+            first_dense_layers=min(self.first_dense_layers, 1),
+            dense_d_ff=min(self.dense_d_ff, 512),
+            kv_lora_rank=min(self.kv_lora_rank, 32),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 16),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 8),
+            v_head_dim=min(self.v_head_dim, 16),
             sliding_window=(min(self.sliding_window, 64)
                             if self.sliding_window else None),
             encoder_layers=min(self.encoder_layers, 2),
@@ -94,10 +118,36 @@ class ModelConfig:
             loss_chunk=0,
         )
 
+    @property
+    def deepseek_moe(self) -> bool:
+        """The DeepSeek-V3 block: latent attention, leading dense layers,
+        then the sigmoid-routed expert layer with shared experts."""
+        return self.family == "moe" and self.moe_router == "sigmoid"
+
+    def _deepseek_counts(self) -> tuple[int, int, int]:
+        """(latent attention, routed expert, MoE layer less its routed
+        experts and attention) parameters of one layer."""
+        D, H, R = self.d_model, self.num_heads, self.kv_lora_rank
+        nope, rope, v = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                         self.v_head_dim)
+        mla = (D * H * (nope + rope) + D * (R + rope) + R
+               + R * H * (nope + v) + H * v * D)
+        expert = 3 * D * self.d_ff
+        rest = (3 * D * self.num_shared_experts * self.d_ff
+                + D * self.num_experts + self.num_experts + 2 * D)
+        return mla, expert, rest
+
     # approximate parameter counts (used by roofline MODEL_FLOPS)
     def param_count(self) -> int:
         D, F, V, L = self.d_model, self.d_ff, self.vocab_size, self.num_layers
         emb = V * D * (1 if self.tie_embeddings else 2)
+        if self.deepseek_moe:         # exact: what models.model.init makes
+            mla, expert, rest = self._deepseek_counts()
+            held = self.experts_held or self.num_experts
+            n_dense = self.first_dense_layers
+            dense = mla + 3 * D * self.dense_d_ff + 2 * D
+            moe = mla + held * expert + rest
+            return emb + D + n_dense * dense + (L - n_dense) * moe
         if self.family in ("dense", "vlm"):
             attn = D * self.num_heads * self.head_dim * 2 \
                 + D * self.num_kv_heads * self.head_dim * 2
@@ -134,6 +184,12 @@ class ModelConfig:
         """Active params per token (MoE: top-k experts only)."""
         if self.family != "moe":
             return self.param_count()
+        if self.deepseek_moe:         # the held experts a token is sent to
+            _, expert, _ = self._deepseek_counts()
+            held = self.experts_held or self.num_experts
+            unused = held - self.experts_per_token * held / self.num_experts
+            moe_layers = self.num_layers - self.first_dense_layers
+            return round(self.param_count() - moe_layers * unused * expert)
         D, F, L = self.d_model, self.d_ff, self.num_layers
         attn = D * self.num_heads * self.head_dim * 2 \
             + D * self.num_kv_heads * self.head_dim * 2

@@ -1,5 +1,10 @@
 """Kimi K2 1T-A32B [arXiv:2501.kimi2] — trillion-parameter MoE, 384 experts
-top-8 (paper-table scale; the stress test for sharded GMoM)."""
+top-8 (paper-table scale; the stress test for sharded GMoM).
+
+Kimi K2 is DeepSeek-V3's architecture; here its latent attention stands as
+GQA with 8 KV heads and its router as softmax with capacity.  The MLA path
+and the DeepSeek-V3 expert layer (``configs/moonlight_16b_a3b.py``) can
+take their place once a sharded share of this model is sized."""
 
 from repro.configs.base import ModelConfig
 

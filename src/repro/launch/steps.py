@@ -129,14 +129,25 @@ def abstract_opt_state(optimizer, params_struct):
 # ---------------------------------------------------------------------------
 # steps
 
-def make_group_grads(cfg: ModelConfig, *, microbatches: int = 1):
+def make_group_grads(cfg: ModelConfig, *, microbatches: int = 1,
+                     with_stats: bool = False):
     """``(params, batch) -> (losses (k,), stacked grads (k, *param))``.
 
     A sequential scan over the k batch-groups (gradient accumulation with
     per-group gradients kept separate): one group's activations live at a
     time, and shard_map regions (MoE EP) stay legal.  Each group is itself
-    data-parallel over the full data axis."""
+    data-parallel over the full data axis.  ``with_stats`` (a config with
+    ``cfg.deepseek_moe``, one microbatch) appends each group's router
+    counters, stacked: ``{"expert_loads": (k, expert layers, held)}``."""
+    if with_stats and microbatches != 1:
+        raise NotImplementedError("router counters with microbatches")
+
     def group_value_and_grad(params, group_batch):
+        if with_stats:
+            (loss, stats), grads = jax.value_and_grad(
+                model_lib.loss_and_stats, has_aux=True)(
+                    params, group_batch, cfg)
+            return loss, grads, stats
         if microbatches == 1:
             return jax.value_and_grad(model_lib.loss_fn)(
                 params, group_batch, cfg)
@@ -165,8 +176,8 @@ def make_group_grads(cfg: ModelConfig, *, microbatches: int = 1):
         def group_step(_, group_batch):
             return None, group_value_and_grad(params, group_batch)
 
-        _, (losses, grads) = jax.lax.scan(group_step, None, batch)
-        return losses, grads
+        _, out = jax.lax.scan(group_step, None, batch)
+        return out
 
     return group_grads
 
@@ -235,7 +246,12 @@ def make_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer, *,
     ``decode``, ``batch_means``, ``trim``, ``weiszfeld``, ``round_kernel``),
     ``optimizer`` and ``step_metrics``; they name the device ops in a
     profile.  ``metrics["weiszfeld_iters"]`` (int32) is the reference
-    Weiszfeld loop's step count, 0 under rules that do not run it.
+    Weiszfeld loop's step count, 0 under rules that do not run it.  A config
+    whose router counts its assignments (``cfg.deepseek_moe``) adds
+    ``moe_local_assignments`` (int32: assignments to the held experts, over
+    the k groups and the expert layers) and ``moe_load_max`` (per layer the
+    largest held expert's load over the held experts' mean, loads summed
+    over the groups; the worst layer).
 
     ``schedule`` threads a multi-round ``AttackSchedule`` through the step
     (the pod-sweep path: attack × schedule at batch-mean granularity).
@@ -244,11 +260,13 @@ def make_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer, *,
     -> (params, opt_state, metrics, attack_state)``; without it the
     historical 5-arg signature is unchanged.
     """
-    group_grads = make_group_grads(cfg, microbatches=microbatches)
+    router_stats = cfg.deepseek_moe
+    group_grads = make_group_grads(cfg, microbatches=microbatches,
+                                   with_stats=router_stats)
 
     def _step_core(params, opt_state, batch, key, round_index, attack_state):
         with jax.named_scope("group_fwd_bwd"):
-            losses, grads = group_grads(params, batch)
+            losses, grads, *stats = group_grads(params, batch)
             if grad_shardings is not None:
                 grads = jax.lax.with_sharding_constraint(grads,
                                                          grad_shardings)
@@ -275,6 +293,12 @@ def make_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer, *,
                        "byz_count": jnp.sum(mask.astype(jnp.int32)),
                        "weiszfeld_iters": info.get(
                            "weiszfeld_iters", jnp.zeros((), jnp.int32))}
+            if router_stats:
+                loads = jnp.sum(stats[0]["expert_loads"], axis=0)
+                mean = jnp.mean(loads.astype(jnp.float32), axis=-1)
+                metrics["moe_local_assignments"] = jnp.sum(loads)
+                metrics["moe_load_max"] = jnp.max(
+                    jnp.max(loads, axis=-1) / jnp.maximum(mean, 1.0))
         return params, opt_state, metrics, attack_state
 
     if schedule is None:

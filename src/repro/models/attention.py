@@ -90,8 +90,10 @@ def attention_core(q, k, v, *, causal: bool, sliding_window: int | None,
                    kv_valid_len=None):
     """Scores/softmax/values for GQA.
 
-    q: (B, Tq, H, hd);  k, v: (B, Tk, KV, hd).  Head grouping is done by
-    reshaping q to (B, Tq, KV, G, hd) — no repeat/materialization of kv.
+    q: (B, Tq, H, hd);  k: (B, Tk, KV, hd);  v: (B, Tk, KV, hv), whose head
+    dim may differ from q/k's (latent attention: 192 against 128).  Head
+    grouping is done by reshaping q to (B, Tq, KV, G, hd) — no
+    repeat/materialization of kv.  Returns (B, Tq, H, hv).
 
     ``q_positions``/``kv_positions`` (B, T) default to arange (prefill);
     decode passes explicit positions.  ``kv_valid_len`` (B,) masks cache tail.
@@ -124,7 +126,7 @@ def attention_core(q, k, v, *, causal: bool, sliding_window: int | None,
     scores = jnp.where(mask, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskh->bqkgh", probs, v)
-    return out.reshape(B, Tq, H, hd)
+    return out.reshape(B, Tq, H, v.shape[-1])
 
 
 def attention_core_blocked(q, k, v, *, causal: bool,
@@ -141,6 +143,7 @@ def attention_core_blocked(q, k, v, *, causal: bool,
     keeps the dry-run roofline honest.  Gradients flow through normally.
 
     Requires default positions (prefill layout, q_pos == kv_pos == arange).
+    v's head dim may differ from q/k's, as in ``attention_core``.
     """
     B, Tq, H, hd = q.shape
     Tk = k.shape[1]
@@ -319,6 +322,78 @@ def apply(params, spec: AttentionSpec, x, *, memory=None, positions=None,
             q_positions=positions, kv_positions=kv_pos)
     out = out.reshape(B, T, spec.num_heads * spec.head_dim)
     return jnp.einsum("btf,fd->btd", out, params["wo"])
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2 §2.1, arXiv:2405.04434; as used
+# by DeepSeek-V3), without query compression
+
+@dataclasses.dataclass(frozen=True)
+class MLASpec:
+    d_model: int
+    num_heads: int
+    kv_lora_rank: int          # width of the compressed KV latent
+    qk_nope_head_dim: int      # per-head query/key dims without rotary
+    qk_rope_head_dim: int      # rotary dims; the key's are shared by heads
+    v_head_dim: int
+    rope_theta: float = 1e4
+    latent_norm_eps: float = 1e-6
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+def mla_init(key, spec: MLASpec, *, dtype):
+    ks = jax.random.split(key, 4)
+    D, H, R = spec.d_model, spec.num_heads, spec.kv_lora_rank
+    hv = spec.v_head_dim
+    return {
+        "wq": layers.dense_init(ks[0], D, (H, spec.qk_head_dim), dtype=dtype),
+        # the latent and the shared rotary key, in one projection
+        "wkv_a": layers.dense_init(ks[1], D, R + spec.qk_rope_head_dim,
+                                   dtype=dtype),
+        "kv_norm": layers.rmsnorm_init(R, dtype=dtype),
+        # per-head keys (without rotary) and values from the latent
+        "wkv_b": layers.dense_init(ks[2], R, (H, spec.qk_nope_head_dim + hv),
+                                   dtype=dtype),
+        "wo": layers.dense_init(ks[3], H * hv, D, dtype=dtype,
+                                scale=(H * hv) ** -0.5),
+    }
+
+
+def mla_apply(params, spec: MLASpec, x):
+    """Causal latent self-attention over positions 0..T-1 (train /
+    prefill).  x: (B, T, D) -> (B, T, D).  The keys are
+    [k_nope_h, rope(k_rope)] with ``k_rope`` one (B, T, rope) projection
+    shared by every head; the scores' scale is ``qk_head_dim ** -0.5``.
+    The projections and the core run under the ``mla`` scope."""
+    B, T, _ = x.shape
+    H, nope, R = spec.num_heads, spec.qk_nope_head_dim, spec.kv_lora_rank
+    with jax.named_scope("mla"):
+        positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
+        q = jnp.einsum("btd,dhk->bthk", x, params["wq"])
+        q_rope = layers.apply_rope(q[..., nope:], positions,
+                                   theta=spec.rope_theta)
+        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        kv_a = jnp.einsum("btd,dr->btr", x, params["wkv_a"])
+        latent = layers.rmsnorm(params["kv_norm"], kv_a[..., :R],
+                                eps=spec.latent_norm_eps)
+        k_rope = layers.apply_rope(kv_a[:, :, None, R:], positions,
+                                   theta=spec.rope_theta)     # (B, T, 1, r)
+        kv = jnp.einsum("btr,rhk->bthk", latent, params["wkv_b"])
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_rope, (B, T, H, spec.qk_rope_head_dim))],
+            axis=-1)
+        v = kv[..., nope:]
+        if T > BLOCKED_ATTENTION_THRESHOLD:
+            out = attention_core_blocked(q, k, v, causal=True,
+                                         sliding_window=None)
+        else:
+            out = attention_core(q, k, v, causal=True, sliding_window=None)
+        out = out.reshape(B, T, H * spec.v_head_dim)
+        return jnp.einsum("btf,fd->btd", out, params["wo"])
 
 
 # ---------------------------------------------------------------------------

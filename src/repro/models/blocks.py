@@ -45,6 +45,26 @@ def moe_spec(cfg: ModelConfig) -> moe.MoESpec:
         capacity_factor=cfg.moe_capacity_factor)
 
 
+def mla_spec(cfg: ModelConfig) -> attention.MLASpec:
+    return attention.MLASpec(
+        d_model=cfg.d_model, num_heads=cfg.num_heads,
+        kv_lora_rank=cfg.kv_lora_rank,
+        qk_nope_head_dim=cfg.qk_nope_head_dim,
+        qk_rope_head_dim=cfg.qk_rope_head_dim,
+        v_head_dim=cfg.v_head_dim, rope_theta=cfg.rope_theta)
+
+
+def deepseek_moe_spec(cfg: ModelConfig) -> moe.DeepSeekMoESpec:
+    return moe.DeepSeekMoESpec(
+        d_model=cfg.d_model, d_ff=cfg.d_ff, num_experts=cfg.num_experts,
+        experts_per_token=cfg.experts_per_token,
+        num_shared=cfg.num_shared_experts,
+        routed_scaling=cfg.routed_scaling,
+        balance_alpha=cfg.balance_alpha,
+        held=cfg.experts_held or cfg.num_experts,
+        held_lo=cfg.experts_held_lo)
+
+
 def rwkv_spec(cfg: ModelConfig) -> rwkv.RWKVSpec:
     return rwkv.RWKVSpec(d_model=cfg.d_model, d_ff=cfg.d_ff,
                          head_dim=cfg.ssm_head_dim)
@@ -119,6 +139,41 @@ def decoder_block_decode(p, cfg: ModelConfig, x, cache, position, *,
     else:
         h = layers.swiglu(p["mlp"], normed)
     return x + h, {"self": new_cache}
+
+
+# DeepSeek-V3 block: pre-norm latent attention + (dense SwiGLU | experts)
+
+def init_deepseek_block(key, cfg: ModelConfig, *, dense: bool):
+    k_attn, k_mlp = jax.random.split(key)
+    p = {
+        "ln_attn": layers.rmsnorm_init(cfg.d_model, dtype=cfg.param_dtype),
+        "attn": attention.mla_init(k_attn, mla_spec(cfg),
+                                   dtype=cfg.param_dtype),
+        "ln_mlp": layers.rmsnorm_init(cfg.d_model, dtype=cfg.param_dtype),
+    }
+    if dense:
+        p["mlp"] = layers.swiglu_init(k_mlp, cfg.d_model, cfg.dense_d_ff,
+                                      dtype=cfg.param_dtype)
+    else:
+        p["moe"] = moe.deepseek_init(k_mlp, deepseek_moe_spec(cfg),
+                                     dtype=cfg.param_dtype)
+    return p
+
+
+def deepseek_block(p, cfg: ModelConfig, x):
+    """x -> (x, balance loss, loads of the held experts (held,) int32).
+    A leading dense layer (``"mlp"`` in p) returns a loss of 0 and no
+    loads."""
+    x = x + attention.mla_apply(
+        p["attn"], mla_spec(cfg),
+        layers.rmsnorm(p["ln_attn"], x, eps=cfg.norm_eps))
+    normed = layers.rmsnorm(p["ln_mlp"], x, eps=cfg.norm_eps)
+    if "mlp" in p:
+        return (x + layers.swiglu(p["mlp"], normed),
+                jnp.zeros((), jnp.float32), None)
+    h, balance, loads = moe.deepseek_apply(p["moe"], deepseek_moe_spec(cfg),
+                                           normed)
+    return x + h, balance, loads
 
 
 # encoder block (audio family): bidirectional self-attn + GELU MLP

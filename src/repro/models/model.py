@@ -3,7 +3,9 @@
 The model is selected by ``cfg.family``:
 
   dense, vlm   — scanned pre-norm GQA decoder (vlm prepends patch embeddings)
-  moe          — same skeleton with MoE FFN + router aux loss
+  moe          — same skeleton with MoE FFN + router aux loss; the
+                 DeepSeek-V3 block (``cfg.deepseek_moe``): latent attention,
+                 leading dense layers, then the sigmoid-routed expert stack
   ssm          — RWKV6 stack (token-shift states instead of KV cache)
   hybrid       — Zamba2: groups of Mamba2 blocks + one *shared* attn block
   audio        — Seamless-style encoder (stub frames) + cross-attn decoder
@@ -39,7 +41,15 @@ def init(key, cfg: ModelConfig):
         params["unembed"] = layers.dense_init(
             k_unembed, cfg.d_model, cfg.vocab_size, dtype=cfg.param_dtype)
 
-    if cfg.family in ("dense", "vlm", "moe"):
+    if cfg.deepseek_moe:
+        if cfg.first_dense_layers:
+            params["dense_layers"] = blocks.init_stacked(
+                lambda k: blocks.init_deepseek_block(k, cfg, dense=True),
+                k_extra, cfg.first_dense_layers)
+        params["layers"] = blocks.init_stacked(
+            lambda k: blocks.init_deepseek_block(k, cfg, dense=False),
+            k_layers, cfg.num_layers - cfg.first_dense_layers)
+    elif cfg.family in ("dense", "vlm", "moe"):
         params["layers"] = blocks.init_stacked(
             lambda k: blocks.init_decoder_block(k, cfg), k_layers,
             cfg.num_layers)
@@ -115,6 +125,25 @@ def _run_decoder_stack(params_stack, cfg: ModelConfig, x, *, memory=None):
     return x, aux
 
 
+def _run_deepseek_stack(params, cfg: ModelConfig, x):
+    """The leading dense layers, then the expert layers, each stack
+    scanned.  Returns (hidden, balance loss, loads (expert layers, held))."""
+    block = blocks.maybe_remat(
+        lambda p, h: blocks.deepseek_block(p, cfg, h), cfg)
+    if "dense_layers" in params:
+        x, _ = jax.lax.scan(lambda h, p: (block(p, h)[0], None), x,
+                            params["dense_layers"])
+
+    def body(carry, p):
+        h, aux = carry
+        h, a, loads = block(p, h)
+        return (h, aux + a), loads
+
+    (x, aux), loads = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32)), params["layers"])
+    return x, aux, loads
+
+
 def _run_rwkv_stack(params_stack, cfg: ModelConfig, x, *, states=None):
     block = blocks.maybe_remat(
         lambda p, h, s: blocks.rwkv_block(p, cfg, h, state=s), cfg)
@@ -170,7 +199,9 @@ def forward(params, cfg: ModelConfig, batch):
         patches = batch["patches"].astype(cfg.dtype)
         x = jnp.concatenate([patches, x], axis=1)
 
-    if cfg.family in ("dense", "vlm", "moe"):
+    if cfg.deepseek_moe:
+        h, aux, _ = _run_deepseek_stack(params, cfg, x)
+    elif cfg.family in ("dense", "vlm", "moe"):
         h, aux = _run_decoder_stack(params["layers"], cfg, x)
     elif cfg.family == "ssm":
         h, _ = _run_rwkv_stack(params["layers"], cfg, x)
@@ -199,6 +230,19 @@ def loss_fn(params, batch, cfg: ModelConfig):
     return ce + aux
 
 
+def loss_and_stats(params, batch, cfg: ModelConfig):
+    """``loss_fn`` of the DeepSeek-V3 block, and the router's counters:
+    ``{"expert_loads": (expert layers, held) int32}``, the assignments each
+    held expert took in each expert layer."""
+    x = _embed(params, cfg, batch["tokens"])
+    h, aux, loads = _run_deepseek_stack(params, cfg, x)
+    h = layers.rmsnorm(params["ln_f"], h, eps=cfg.norm_eps)
+    ce = layers.cross_entropy_loss(
+        _unembed_fn(params, cfg), h, batch["labels"],
+        vocab_chunk=cfg.loss_chunk)
+    return ce + aux, {"expert_loads": loads}
+
+
 def logits(params, cfg: ModelConfig, batch):
     """Full logits (small-scale tests only — O(B·T·V) memory)."""
     h, _ = forward(params, cfg, batch)
@@ -213,6 +257,8 @@ def init_decode_state(cfg: ModelConfig, batch: int, max_len: int):
 
     For attention families this is the KV cache the decode_32k / long_500k
     shapes size against; for SSM/hybrid it is O(1) recurrent state."""
+    if cfg.deepseek_moe:
+        raise NotImplementedError("no decode path for latent attention")
     spec = blocks.attn_spec(cfg)
     if cfg.family in ("dense", "vlm", "moe"):
         cache = {"self": attention.init_cache(spec, batch, max_len,

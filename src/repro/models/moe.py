@@ -10,11 +10,20 @@ all-to-all-style collectives when the expert axis is sharded over ``model``
 Tokens beyond an expert's capacity are dropped (standard; capacity_factor
 controls the slack).  The router adds the usual load-balance auxiliary loss
 (Switch/GShard form) and optional router z-loss.
+
+The DeepSeek-V3 layer (``deepseek_*``, arXiv:2412.19437 §2.1.2) is apart
+from that path: sigmoid scores, a selection bias that picks the experts but
+never weighs them, the chosen weights normalised and scaled, shared experts
+on every token, the sequence-wise balance loss, and a dropless dispatch
+over the experts this chip holds.  Its router scores every expert of the
+layer; only the held experts' part of the result is computed, as expert
+parallelism computes it on each chip.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -310,3 +319,176 @@ def _apply_dense(params, spec: MoESpec, x):
 
     aux_total = spec.router_aux_weight * aux + spec.router_z_weight * z
     return out, aux_total
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V3 expert layer
+
+@dataclasses.dataclass(frozen=True)
+class DeepSeekMoESpec:
+    d_model: int
+    d_ff: int                  # width of one routed expert
+    num_experts: int           # the router's outputs: every expert
+    experts_per_token: int
+    num_shared: int            # shared experts, one SwiGLU num_shared*d_ff wide
+    routed_scaling: float
+    balance_alpha: float       # weight of the sequence-wise balance loss
+    held: int                  # this chip holds experts [held_lo, held_lo+held)
+    held_lo: int = 0
+
+
+def deepseek_init(key, spec: DeepSeekMoESpec, *, dtype):
+    k_router, k_gate, k_up, k_down, k_shared = jax.random.split(key, 5)
+    E, D, F = spec.held, spec.d_model, spec.d_ff
+
+    def expert_init(k, d_in, d_out):
+        return layers.truncated_normal_init(
+            k, (E, d_in, d_out), d_in ** -0.5, dtype)
+
+    return {
+        "router": layers.dense_init(k_router, D, spec.num_experts,
+                                    dtype=dtype),
+        # DeepSeek-V3's e_score_correction_bias: set by a load rule outside
+        # the gradient (none here), so no gradient reaches it
+        "router_bias": jnp.zeros((spec.num_experts,), jnp.float32),
+        "experts": {"w_gate": expert_init(k_gate, D, F),
+                    "w_up": expert_init(k_up, D, F),
+                    "w_down": expert_init(k_down, F, D)},
+        "shared": layers.swiglu_init(k_shared, D, spec.num_shared * F,
+                                     dtype=dtype),
+    }
+
+
+def deepseek_route(params, spec: DeepSeekMoESpec, x):
+    """x: (B, T, D) -> (expert ids (B*T, K), weights (B*T, K) f32, balance
+    loss).  The ids are the top-k of score + bias, the weights the chosen
+    scores normalised over the k and scaled.  The balance loss is
+    ``alpha * sum_i f_i P_i`` per sequence (eqs. 17-20), averaged over the
+    sequences: f_i the share of the sequence's top-k picks of the plain
+    scores on expert i, times E / K; P_i the mean of its scores normalised
+    over the experts."""
+    B, T, D = x.shape
+    E, K = spec.num_experts, spec.experts_per_token
+    logits = jnp.einsum("nd,de->ne", x.reshape(B * T, D).astype(jnp.float32),
+                        params["router"].astype(jnp.float32))
+    scores = jax.nn.sigmoid(logits)                            # (N, E)
+    bias = jax.lax.stop_gradient(params["router_bias"].astype(jnp.float32))
+    _, ids = jax.lax.top_k(scores + bias, K)
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * spec.routed_scaling
+
+    _, plain = jax.lax.top_k(scores, K)
+    picks = jnp.sum(jax.nn.one_hot(plain, E, dtype=jnp.float32), axis=1)
+    f = jnp.mean(picks.reshape(B, T, E), axis=1) * (E / K)     # (B, E)
+    p = jnp.mean((scores / jnp.sum(scores, axis=-1, keepdims=True))
+                 .reshape(B, T, E), axis=1)
+    balance = spec.balance_alpha * jnp.mean(jnp.sum(f * p, axis=-1))
+    return ids, w, balance
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _take_rows(x, idx, mask, back_idx, back_mask, fold):
+    """``where(mask, x[idx], 0)``, whose gradient is again a gather:
+    ``x``'s row i takes the rows ``back_idx[j * len(x) + i]``, j < fold, of
+    the cotangent where ``back_mask``.  Exact where these list, for each row
+    of ``x``, the masked rows that read it (a permutation and its inverse),
+    so that neither direction needs a scatter."""
+    return jnp.where(mask[:, None], x[idx], 0).astype(x.dtype)
+
+
+def _take_rows_fwd(x, idx, mask, back_idx, back_mask, fold):
+    return (_take_rows(x, idx, mask, back_idx, back_mask, fold),
+            (back_idx, back_mask))
+
+
+def _take_rows_bwd(fold, res, g):
+    back_idx, back_mask = res
+    gx = jnp.where(back_mask[:, None], g[back_idx], 0)
+    gx = jnp.sum(gx.reshape(fold, -1, g.shape[-1]), axis=0).astype(g.dtype)
+    return gx, None, None, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.custom_vjp
+def _weighted_sum(w, y):
+    """``sum_k w[k, :, None] * y[k]`` in f32: w (K, N) f32, y (K, N, D).
+    Its gradient writes y's cotangent in y's dtype and reduces w's, one k
+    at a time; autodiff's holds an f32 (K, N, D) broadcast of the output's
+    cotangent."""
+    return jnp.sum(w[:, :, None] * y.astype(jnp.float32), axis=0)
+
+
+def _weighted_sum_fwd(w, y):
+    return _weighted_sum(w, y), (w, y)
+
+
+def _weighted_sum_bwd(res, g):
+    w, y = res
+    # one k at a time: a (K, N, D) broadcast of g would be materialised
+    gw = jnp.stack([jnp.sum(g * y[k].astype(jnp.float32), axis=-1)
+                    for k in range(y.shape[0])])
+    gy = jnp.stack([(w[k][:, None] * g).astype(y.dtype)
+                    for k in range(y.shape[0])])
+    return gw, gy
+
+
+_weighted_sum.defvjp(_weighted_sum_fwd, _weighted_sum_bwd)
+
+
+def _sort_by_expert(spec: DeepSeekMoESpec, ids):
+    """The assignments (k, token), flattened k-major (assignment k*N + t),
+    sorted by held expert, the others after them.  Returns ``(order, pos,
+    held, loads)``: ``order[a]`` the assignment at sorted row a, ``pos`` its
+    inverse, ``held`` whether an assignment's expert is held here,
+    ``loads`` (held,) int32 the rows of each held expert."""
+    n_assign = ids.size
+    local = ids.T.reshape(-1) - spec.held_lo
+    held = (local >= 0) & (local < spec.held)
+    bucket = jnp.where(held, local, spec.held)
+    order = jnp.argsort(bucket, stable=True).astype(jnp.int32)
+    pos = jnp.zeros((n_assign,), jnp.int32).at[order].set(
+        jnp.arange(n_assign, dtype=jnp.int32))
+    loads = jnp.sum(
+        (bucket[:, None] == jnp.arange(spec.held)[None]).astype(jnp.int32),
+        axis=0)
+    return order, pos, held, loads
+
+
+def deepseek_apply(params, spec: DeepSeekMoESpec, x):
+    """x: (B, T, D) -> (out (B, T, D), balance loss, loads (held,) int32).
+
+    Dropless: every assignment to a held expert is computed.  The buffer
+    holds ``B*T*min(K, held)`` rows, as many as the held experts can ever
+    receive; the rows past the held assignments are padding, outside every
+    group of the grouped matmuls, which skip them.  Scopes: ``router``,
+    ``dispatch`` (sort, permute, unpermute), ``experts`` (the grouped
+    matmuls), ``shared_experts``."""
+    B, T, D = x.shape
+    N, K = B * T, spec.experts_per_token
+    rows = N * min(K, spec.held)
+    x_flat = x.reshape(N, D)
+    with jax.named_scope("router"):
+        ids, w, balance = deepseek_route(params, spec, x)
+    with jax.named_scope("dispatch"):
+        order, pos, held, loads = _sort_by_expert(spec, ids)
+        order = order[:rows]
+        row_held = held[order]
+        slot = jnp.minimum(pos, rows - 1)
+        x_sorted = _take_rows(x_flat, order % N, row_held, slot, held, K)
+    with jax.named_scope("experts"):
+        ex = params["experts"]
+        gate = jax.lax.ragged_dot(x_sorted, ex["w_gate"], loads)
+        up = jax.lax.ragged_dot(x_sorted, ex["w_up"], loads)
+        act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
+        y_sorted = jax.lax.ragged_dot(act, ex["w_down"], loads)
+    with jax.named_scope("dispatch"):
+        # k-major rows, so that (K*N, D) -> (K, N, D) moves no data
+        y = _take_rows(y_sorted, slot, held, order, row_held, 1)
+        w = jnp.where(held.reshape(K, N), w.T, 0.0)
+        routed = _weighted_sum(w, y.reshape(K, N, D))
+    with jax.named_scope("shared_experts"):
+        shared = layers.swiglu(params["shared"], x_flat)
+    out = (routed + shared.astype(jnp.float32)).astype(x.dtype)
+    return out.reshape(B, T, D), balance, loads

@@ -30,7 +30,9 @@ ROUND_SCOPES = ("worker_grads", "attack", "aggregate", "optimizer",
 # their consumers and name no op of its own after them: the Weiszfeld
 # combine's multiply-add chain (into the step's metrics and update)
 FUSED_SCOPES = ("combine",)
-ALL_SCOPES = set(STEP_SCOPES + ROUND_SCOPES + FUSED_SCOPES) | {
+# the DeepSeek-V3 block's layers, inside the group step's fwd/bwd
+MOE_SCOPES = ("mla", "router", "dispatch", "experts", "shared_experts")
+ALL_SCOPES = set(STEP_SCOPES + ROUND_SCOPES + FUSED_SCOPES + MOE_SCOPES) | {
     "encode", "decode", "round_kernel"}
 # what the CPU compiler leaves outside every scope: the arguments, and
 # instructions it makes itself, without an op_name of a traced operation
@@ -47,6 +49,20 @@ def _tiny(k=K, **rc_kw):
     stream = TokenStream(vocab_size=cfg.vocab_size, seq_len=32,
                          global_batch=2 * k, num_workers=k, seed=0)
     return cfg, RobustConfig(**kw), stream
+
+
+def _tiny_moe(k=K):
+    """The DeepSeek-V3 block at a test size: a dense layer and two expert
+    layers of 8 experts, top-3, of which experts 2-3 are held."""
+    from repro.configs.moonlight_16b_a3b import CHIP_SHARE
+    cfg = CHIP_SHARE.reduced().with_(num_layers=3, num_experts=8,
+                                     experts_per_token=3, experts_held=2,
+                                     experts_held_lo=2)
+    rc = RobustConfig(num_workers=k, num_byzantine=1, num_batches=k,
+                      attack="sign_flip", aggregator="gmom")
+    stream = TokenStream(vocab_size=cfg.vocab_size, seq_len=32,
+                         global_batch=2 * k, num_workers=k, seed=0)
+    return cfg, rc, stream
 
 
 def _compiled_step(cfg, rc, stream):
@@ -121,6 +137,55 @@ def test_a_dropped_or_renamed_scope_is_caught(gmom_step_text, renamed,
     assert bool(traced) == outermost
     doctored = gmom_step_text.replace(f"/{renamed}/", f"/{renamed}_x/")
     assert scope_problems(doctored, STEP_SCOPES)[0] == [renamed]
+
+
+@pytest.fixture(scope="module")
+def moe_step_text():
+    return _compiled_step(*_tiny_moe()).as_text()
+
+
+def test_expert_layers_are_scoped_under_fwd_bwd(moe_step_text):
+    missing, traced, _ = scope_problems(
+        moe_step_text, ("group_fwd_bwd", "aggregate", "optimizer")
+        + MOE_SCOPES)
+    assert missing == [] and traced == []
+    # every traced operation's path (a reduction's inner computation
+    # carries its scope alone)
+    for path in re.findall(r'op_name="(jit\([^"]*)"', moe_step_text):
+        parts = path.split("/")
+        for scope in set(MOE_SCOPES).intersection(parts):
+            assert "group_fwd_bwd" in parts[:parts.index(scope)], parts
+
+
+def test_router_counters_are_the_reference_routing():
+    """``moe_local_assignments`` and ``moe_load_max`` are what the plain
+    reference's routing of the step's k groups gives."""
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.reference import mla_moe_lm
+    from test_mla_moe import ref_cfg
+    cfg, rc, stream = _tiny_moe()
+    params = model_lib.init(jax.random.PRNGKey(0), cfg)
+    batch = stream.batch(0)
+    opt = optim.sgd(0.1)
+    _, _, metrics = jax.jit(steps.make_group_train_step(cfg, rc, opt))(
+        params, opt.init(params), batch, jax.random.PRNGKey(5), jnp.int32(0))
+    ref = dict(ref_cfg(cfg), first_k_dense_replace=cfg.first_dense_layers)
+    cfg_t = tuple(sorted(ref.items()))
+    loads = 0
+    for g in range(K):
+        ids = np.asarray(mla_moe_lm.routing(params, batch["tokens"][g],
+                                            cfg_t)) - cfg.experts_held_lo
+        loads = loads + np.stack([np.bincount(
+            i[(i >= 0) & (i < cfg.experts_held)], minlength=cfg.experts_held)
+            for i in ids.reshape(ids.shape[0], -1)])
+    assert int(metrics["moe_local_assignments"]) == loads.sum() > 0
+    np.testing.assert_allclose(
+        metrics["moe_load_max"], np.max(loads.max(-1) / loads.mean(-1)),
+        rtol=1e-6)
 
 
 def test_wire_codec_is_scoped():
