@@ -68,6 +68,10 @@ class ModelConfig:
     # --- numerics / memory ---
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
+    # checkpoint each block (jax.checkpoint); what it keeps for the backward
+    # is not set here: launch.steps.jit_group_train_step saves the blocks'
+    # projection and MLP matmuls where the compiled step fits the device's
+    # memory, and recomputes the whole block elsewhere
     remat: bool = True
     loss_chunk: int = 512
 

@@ -35,6 +35,7 @@ from repro.configs.base import InputShape, ModelConfig
 from repro.core import RobustConfig, byzantine
 from repro.core.robust_train import aggregate_reported
 from repro.launch import sharding
+from repro.models import blocks
 from repro.models import model as model_lib
 
 
@@ -212,7 +213,7 @@ def group_grad_layout(params, mesh, cfg: ModelConfig, *,
 def make_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer, *,
                           microbatches: int = 1, grad_shardings=None,
                           schedule: byzantine.AttackSchedule | None = None,
-                          shard_spec=None):
+                          shard_spec=None, remat_policy=None):
     """Group-mode robust train step (the production/dry-run path).
 
     rc.num_workers is interpreted as k (the number of batches); the attack
@@ -259,13 +260,18 @@ def make_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer, *,
     ``train_step(params, opt_state, batch, key, round_index, attack_state)
     -> (params, opt_state, metrics, attack_state)``; without it the
     historical 5-arg signature is unchanged.
+
+    ``remat_policy`` is the ``jax.checkpoint`` policy of the model's blocks
+    under ``cfg.remat`` (None: full remat; ``jit_group_train_step`` chooses
+    it from the device's memory).
     """
     router_stats = cfg.deepseek_moe
     group_grads = make_group_grads(cfg, microbatches=microbatches,
                                    with_stats=router_stats)
 
     def _step_core(params, opt_state, batch, key, round_index, attack_state):
-        with jax.named_scope("group_fwd_bwd"):
+        with jax.named_scope("group_fwd_bwd"), \
+                blocks.remat_policy(remat_policy):
             losses, grads, *stats = group_grads(params, batch)
             if grad_shardings is not None:
                 grads = jax.lax.with_sharding_constraint(grads,
@@ -315,6 +321,41 @@ def make_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer, *,
     return train_step
 
 
+# Share of the device's memory a step saving its dots leaves free: the
+# compiled peak counts neither what the process holds beside the step (the
+# next batch, the caller's own arrays) nor the allocator's fragmentation.
+REMAT_MARGIN = 1 / 16
+
+
+def device_bytes_limit(device) -> int | None:
+    """The bytes a program may hold on ``device`` (its allocator's
+    ``bytes_limit``), or None where the runtime reports none: the CPU, and
+    the described devices a dry run lowers for."""
+    try:
+        stats = device.memory_stats()
+    except jax.errors.JaxRuntimeError:
+        return None
+    return (stats or {}).get("bytes_limit")
+
+
+def choose_remat_policy(compile_saving_dots, bytes_limit: int | None):
+    """``blocks.SAVE_DOTS`` where ``compile_saving_dots()`` compiles and its
+    peak memory stays under ``bytes_limit`` less ``REMAT_MARGIN`` of it;
+    otherwise None, full remat.  A ``bytes_limit`` of None is full remat,
+    with nothing compiled."""
+    if bytes_limit is None:
+        return None
+    try:
+        compiled = compile_saving_dots()
+    except jax.errors.JaxRuntimeError as e:
+        if "RESOURCE_EXHAUSTED" not in str(e):
+            raise
+        return None
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    return (blocks.SAVE_DOTS if peak <= (1 - REMAT_MARGIN) * bytes_limit
+            else None)
+
+
 def jit_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer,
                          params, opt_state, batch, *, mesh=None,
                          gather_grads: bool = False, microbatches: int = 1,
@@ -331,32 +372,53 @@ def jit_group_train_step(cfg: ModelConfig, rc: RobustConfig, optimizer,
     ShapeDtypeStructs: only their shapes are read.  ``target_backend`` keys
     the round-backend dispatch (a dry run lowers TPU programs on a CPU host).
 
+    Under ``cfg.remat`` the blocks' checkpoint policy is chosen here: the
+    step is compiled saving its dots (``blocks.SAVE_DOTS``) for a PRNG key
+    of ``(2,) uint32`` and an ``int32`` round, and kept if it fits the
+    device (``choose_remat_policy``), else jitted with full remat.  A caller
+    that compiles the kept step for those arguments gets that same program
+    back from the compile cache.
+
     Returns ``(jitted_step, in_shardings)``; ``in_shardings`` is None
     without a mesh, else ``(params, opt_state, batch, key, round[,
     attack_state])`` shardings.
     """
-    if mesh is None:
-        step = make_group_train_step(cfg, rc, optimizer,
-                                     microbatches=microbatches,
-                                     schedule=schedule)
-        return jax.jit(step, donate_argnums=(0, 1)), None
-    pshard = sharding.param_shardings(params, mesh, cfg, fsdp=fsdp)
-    oshard = sharding.opt_state_shardings(opt_state, params, mesh, cfg,
-                                          fsdp=fsdp)
-    bshard = sharding.batch_shardings(batch, mesh)
-    gshard, spec = group_grad_layout(params, mesh, cfg,
-                                     gather_grads=gather_grads, fsdp=fsdp,
-                                     target_backend=target_backend)
-    step = make_group_train_step(cfg, rc, optimizer,
-                                 microbatches=microbatches,
-                                 grad_shardings=gshard, schedule=schedule,
-                                 shard_spec=spec)
-    rep = sharding.replicated(mesh)
-    in_shardings = (pshard, oshard, bshard, rep, rep)
+    kw = dict(microbatches=microbatches, schedule=schedule)
+    jit_kw, in_shardings = {}, None
+    if mesh is not None:
+        pshard = sharding.param_shardings(params, mesh, cfg, fsdp=fsdp)
+        oshard = sharding.opt_state_shardings(opt_state, params, mesh, cfg,
+                                              fsdp=fsdp)
+        bshard = sharding.batch_shardings(batch, mesh)
+        gshard, spec = group_grad_layout(params, mesh, cfg,
+                                         gather_grads=gather_grads,
+                                         fsdp=fsdp,
+                                         target_backend=target_backend)
+        kw.update(grad_shardings=gshard, shard_spec=spec)
+        rep = sharding.replicated(mesh)
+        in_shardings = (pshard, oshard, bshard, rep, rep)
+        if schedule is not None:
+            in_shardings += (jax.tree.map(
+                lambda _: rep, jax.eval_shape(schedule.init_state)),)
+        jit_kw["in_shardings"] = in_shardings
+
+    def jit_with(policy):
+        step = make_group_train_step(cfg, rc, optimizer, remat_policy=policy,
+                                     **kw)
+        return jax.jit(step, donate_argnums=(0, 1), **jit_kw)
+
+    if not cfg.remat:
+        return jit_with(None), in_shardings
+    saving_dots = jit_with(blocks.SAVE_DOTS)
+    args = (params, opt_state, batch,
+            jax.ShapeDtypeStruct((2,), jnp.uint32),
+            jax.ShapeDtypeStruct((), jnp.int32))
     if schedule is not None:
-        in_shardings += (jax.tree.map(
-            lambda _: rep, jax.eval_shape(schedule.init_state)),)
-    return (jax.jit(step, in_shardings=in_shardings, donate_argnums=(0, 1)),
+        args += (jax.eval_shape(schedule.init_state),)
+    device = jax.devices()[0] if mesh is None else mesh.devices.flat[0]
+    policy = choose_remat_policy(lambda: saving_dots.lower(*args).compile(),
+                                 device_bytes_limit(device))
+    return (saving_dots if policy is not None else jit_with(None),
             in_shardings)
 
 

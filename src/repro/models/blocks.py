@@ -4,11 +4,19 @@ Every stack uses ``jax.lax.scan`` over the layer axis (params carry a leading
 L dim, initialized with vmap) so the lowered HLO is O(1) in depth — the
 512-device dry-run of the 80-layer configs depends on this.  ``cfg.remat``
 wraps the block body in ``jax.checkpoint`` (activation rematerialization).
+What the checkpoint keeps is the train step's choice, not the config's:
+``launch.steps.jit_group_train_step`` compiles the step with ``SAVE_DOTS``
+(each block's projection and MLP matmul outputs kept for the backward; the
+attention core, norms, rotary and activations recomputed) and keeps it
+where the compiled peak fits the device's memory, else compiles full remat
+(every value recomputed).  The step traces its stacks inside
+``remat_policy(...)``; outside one, ``maybe_remat`` is full remat.
 """
 
 from __future__ import annotations
 
-import functools
+import contextlib
+import contextvars
 
 import jax
 import jax.numpy as jnp
@@ -263,5 +271,25 @@ def init_stacked(init_fn, key, num: int):
     return jax.vmap(init_fn)(keys)
 
 
+# matmuls whose operands share no batch dimension: the q/k/v/o, latent and
+# MLP projections, not the attention core's score and value products
+SAVE_DOTS = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+
+_REMAT_POLICY = contextvars.ContextVar("remat_policy", default=None)
+
+
+@contextlib.contextmanager
+def remat_policy(policy):
+    """Trace ``maybe_remat``'s checkpoints inside with ``policy`` (None:
+    full remat)."""
+    token = _REMAT_POLICY.set(policy)
+    try:
+        yield
+    finally:
+        _REMAT_POLICY.reset(token)
+
+
 def maybe_remat(fn, cfg: ModelConfig):
-    return jax.checkpoint(fn) if cfg.remat else fn
+    if not cfg.remat:
+        return fn
+    return jax.checkpoint(fn, policy=_REMAT_POLICY.get())

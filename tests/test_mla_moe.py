@@ -156,8 +156,11 @@ def test_held_shares_add_up_to_the_uncut_layer():
                                                    MM)[0], **TOL)
 
 
-@pytest.mark.parametrize("remat", [False, True])
-def test_model_loss_and_grads_match_reference(remat):
+@pytest.mark.parametrize("remat,policy", [
+    pytest.param(False, None, id="False"),
+    pytest.param(True, None, id="True"),
+    pytest.param(True, blocks.SAVE_DOTS, id="dots")])
+def test_model_loss_and_grads_match_reference(remat, policy):
     cfg = small(remat=remat)
     params = M.init(jax.random.PRNGKey(10), cfg)
     params["layers"]["moe"]["router_bias"] = normal(
@@ -165,8 +168,9 @@ def test_model_loss_and_grads_match_reference(remat):
     tokens = jax.random.randint(jax.random.PRNGKey(12), (2, 32), 0,
                                 cfg.vocab_size)
     labels = jnp.roll(tokens, -1, axis=-1)
-    loss, grads = jax.value_and_grad(M.loss_fn)(
-        params, {"tokens": tokens, "labels": labels}, cfg)
+    with blocks.remat_policy(policy):
+        loss, grads = jax.value_and_grad(M.loss_fn)(
+            params, {"tokens": tokens, "labels": labels}, cfg)
     want, want_grads = jax.value_and_grad(ref.group_loss)(
         params, tokens, labels, ref_cfg(cfg))
     np.testing.assert_allclose(loss, want, rtol=1e-6)
