@@ -40,9 +40,8 @@ def save_bench(name: str, payload: dict) -> str:
     committed), BENCH files are committed with the PR that produced them so
     reviewers and later sessions can read the performance trajectory from
     git history.  See docs/BENCHMARKS.md for the workflow and field
-    conventions.  Metadata records the backend the numbers were taken on —
-    a fused-kernel speedup measured on CPU says nothing about TPU and
-    vice versa.
+    conventions.  Metadata records the backend the numbers were taken on:
+    a timing measured on CPU says nothing about TPU and vice versa.
     """
     record = {
         "bench": name,
@@ -57,28 +56,6 @@ def save_bench(name: str, payload: dict) -> str:
         json.dump(record, f, indent=1, sort_keys=False)
         f.write("\n")
     return path
-
-
-def ab_time(fn_a, fn_b, *args, iters: int = 30, warmup: int = 5):
-    """Interleaved A/B wall-time (median us per call for each function).
-
-    Alternating the two measurements inside one loop cancels machine-load
-    drift that back-to-back loops pick up — required for honest fused vs
-    unfused comparisons on shared CI hosts.
-    """
-    for _ in range(warmup):
-        jax.block_until_ready(fn_a(*args))
-        jax.block_until_ready(fn_b(*args))
-    ta, tb = [], []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn_a(*args))
-        ta.append(time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn_b(*args))
-        tb.append(time.perf_counter() - t0)
-    med = lambda ts: float(sorted(ts)[len(ts) // 2] * 1e6)  # noqa: E731
-    return med(ta), med(tb)
 
 
 def run_linreg(*, dim, total_samples, num_workers, num_byzantine,

@@ -3,17 +3,9 @@
 Claims checked:
   (a) Weiszfeld reaches the (1+gamma)-approximation with gamma = 1/N in few
       iterations (the paper invokes [CLM+16]'s O(qd log^3 N); we substitute
-      Weiszfeld — DESIGN.md §3 — and measure its iteration count & wall time).
+      Weiszfeld and measure its iteration count & wall time).
   (b) cost scales ~ linearly in d and in k (the paper's O(md + qd log^3 N)
       is linear in d at fixed k).
-  (c) the fused Pallas kernel step agrees with the jnp step (interpret mode)
-      and its VMEM working set stays in budget.
-  (d) the fused ROUND kernel (kernels/geomed/round.py: grads -> batch means
-      -> trim -> full Weiszfeld in one VMEM-resident pass) is bit-identical
-      to its jnp reference in interpret mode, its resident set stays in
-      budget across the (k, d) sweep, and the fused formulation's wall time
-      is recorded against the unfused pipeline (the checked-in
-      BENCH_round_kernel.json carries the full sweep; see docs/BENCHMARKS.md).
 """
 
 from __future__ import annotations
@@ -73,55 +65,6 @@ def main() -> dict:
         times_k.append(us)
         print(f"geomed_cost,k={k},us_per_call={us:.0f}")
     out["time_vs_k"] = {"k": [4, 8, 16, 32, 64], "us": times_k}
-
-    # (c) kernel step agreement + VMEM budget
-    from repro.kernels.geomed import geomed as gk, ref as gref
-    pts = jnp.asarray(rng.normal(size=(32, 8192)).astype(np.float32))
-    y = jnp.mean(pts, axis=0)
-    w = jnp.ones((32,))
-    kout = gk.weiszfeld_step(pts, y, w, interpret=True)
-    rout = gref.weiszfeld_step_ref(pts, y, w)
-    err = float(jnp.max(jnp.abs(kout - rout)))
-    vmem_bytes = 32 * gk.TILE_D * 4 * 2   # z tile + partials, double-buffered
-    out["kernel"] = {"max_err_vs_ref": err, "tile_d": gk.TILE_D,
-                     "vmem_working_set_bytes": vmem_bytes,
-                     "vmem_budget_bytes": 16 * 2**20}
-    print(f"geomed_cost,kernel_err={err:.2e},"
-          f"vmem_working_set={vmem_bytes/2**10:.0f}KiB")
-
-    # (d) fused round kernel: bit-agreement, VMEM residency, fused-vs-unfused
-    from benchmarks.common import ab_time
-    from repro.core import aggregators
-    from repro.core.grouping import make_grouping
-    from repro.kernels.geomed import round as round_kernel
-
-    rows = []
-    for (m, k, d) in [(20, 10, 1000), (50, 11, 4096)]:
-        g = jnp.asarray(rng.normal(size=(m, d)).astype(np.float32))
-        grouping = make_grouping(m, k)
-        ker = round_kernel.round_aggregate_kernel(g, grouping,
-                                                  interpret=True,
-                                                  max_iters=16)
-        ref = round_kernel.round_aggregate_ref(g, grouping, max_iters=16)
-        unfused = jax.jit(lambda x, k=k: aggregators.gmom_aggregator(
-            x, num_batches=k, round_backend="reference", max_iters=16))
-        fused = jax.jit(lambda x, grouping=grouping:
-                        round_kernel.round_aggregate_ref(
-                            x, grouping, max_iters=16))
-        tu, tf = ab_time(unfused, fused, g, iters=15)
-        resident = round_kernel.round_resident_bytes(m, k, d)
-        rows.append({
-            "m": m, "k": k, "d": d,
-            "bit_identical": bool(np.array_equal(np.asarray(ker),
-                                                 np.asarray(ref))),
-            "unfused_us": tu, "fused_us": tf,
-            "vmem_resident_bytes": resident,
-            "vmem_budget_bytes": round_kernel.VMEM_BUDGET_BYTES,
-        })
-        print(f"geomed_cost,round_kernel,m={m},k={k},d={d},"
-              f"bit_identical={rows[-1]['bit_identical']},"
-              f"resident={resident / 2**10:.0f}KiB")
-    out["round_kernel"] = rows
 
     save_json("geomed_cost.json", out)
     return out

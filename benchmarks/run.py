@@ -1,4 +1,4 @@
-"""Benchmark harness — one module per paper claim (see DESIGN.md §6).
+"""Benchmark harness — one module per paper claim.
 
 Prints ``name,metric,...`` CSV lines and writes JSON under
 benchmarks/results/.  Roofline tables come from the dry-run
@@ -15,7 +15,7 @@ import time
 import traceback
 
 BENCHES = ["convergence", "error_scaling", "breakdown", "geomed_cost",
-           "communication", "kernel_bench", "lm_attack",
+           "communication", "lm_attack",
            "selection_rules", "gmom_variants", "noniid"]
 
 

@@ -8,9 +8,6 @@ Fails (non-zero exit / raised AssertionError from pytest) when:
 * a registry entry has an empty description (the registry IS the
   documentation surface — see aggregators.describe());
 * the README aggregator table is missing a registered aggregator;
-* the checked-in benchmarks/BENCH_round_kernel.json is absent, unparsable,
-  or its recorded headline claim (fused beats unfused at the paper-scale
-  configuration on the recorded backend) does not hold;
 * a registered pod-sweep scenario or production mesh (repro.sim.sweep) is
   missing from the checked-in benchmarks/BENCH_pod_sweeps.json, or a
   sweep-matrix axis value (attack/schedule/aggregator/mesh) is missing
@@ -80,30 +77,6 @@ def collect_problems() -> list[str]:
                 "README aggregator table drifted from the registry; "
                 f"missing row: {row!r} "
                 "(regenerate with repro.core.aggregators.describe_markdown())")
-
-    bench_path = os.path.join("benchmarks", "BENCH_round_kernel.json")
-    if not os.path.exists(os.path.join(REPO, bench_path)):
-        problems.append(f"{bench_path} is not checked in "
-                        "(run python -m benchmarks.run --only kernel_bench)")
-    else:
-        try:
-            rec = json.loads(_read(bench_path))
-        except json.JSONDecodeError as e:
-            problems.append(f"{bench_path} does not parse: {e}")
-        else:
-            for field in ("backend", "paper_scale", "summary"):
-                if field not in rec:
-                    problems.append(f"{bench_path} missing field {field!r}")
-            summary = rec.get("summary", {})
-            if not summary.get("fused_beats_unfused_at_paper_scale", False):
-                problems.append(
-                    f"{bench_path}: recorded summary does not claim the "
-                    "paper-scale fused win — re-measure or re-record")
-            for row in rec.get("paper_scale", []):
-                if row.get("speedup", 0.0) <= 1.0:
-                    problems.append(
-                        f"{bench_path}: paper_scale row {row} has "
-                        "speedup <= 1")
 
     problems += _pod_sweep_problems(paper_map)
     problems += _codec_problems(paper_map)
@@ -359,7 +332,7 @@ def main() -> int:
         print(f"check_docs: FAILED ({len(problems)} problem(s))")
         return 1
     print("check_docs: ok — registries, PAPER_MAP, README table, "
-          "BENCH_round_kernel.json, the pod-sweep record/docs, the "
+          "the pod-sweep record/docs, the "
           "repro.verify rule catalog, the Layer-C taint tables, the "
           "ASYNC.md arrival table, and every doc-referenced file path "
           "are consistent")

@@ -4,7 +4,7 @@ multi-pod JAX/TPU training & serving framework.
 Subpackages:
     core        the paper's algorithm (geomed, aggregators, attacks, steps)
     models      the 10-assigned-architecture model zoo
-    kernels     Pallas TPU kernels (geomed Weiszfeld, flash attention)
+    kernels     the fused GMoM round, a Pallas TPU kernel
     data        synthetic deterministic pipelines (+ the paper's linreg)
     optim       SGD (paper) / AdamW
     checkpoint  msgpack pytree checkpoints

@@ -40,7 +40,6 @@ from repro.core.robust_train import (  # noqa: F401
     make_robust_train_step,
     make_run_rounds,
     make_sharded_aggregate,
-    make_shardmap_aggregate,
     per_worker_grads,
     schedule_from_config,
 )

@@ -1,7 +1,7 @@
 """Byzantine-robust distributed training step.
 
 This is the paper's Algorithm 2 realized as a single jit/pjit-able SPMD
-function (DESIGN.md §3-4):
+function:
 
     per-worker grads (vmap over the worker-sharded batch axis)
       -> simulated Byzantine corruption of reported gradients
@@ -21,15 +21,12 @@ every other line of code.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import aggregators, byzantine
-from repro.core.geometric_median import (
-    batch_mean_norms, geometric_median_pytree, trim_weights)
 
 # repro: train-scan — the multi-round scan carry below is the bit-exact
 # resume surface: every carry element must be a TrainState field (PR 2
@@ -399,87 +396,6 @@ def make_run_rounds(loss_fn: Callable, optimizer, cfg: RobustConfig, *,
     return run
 
 
-# ---------------------------------------------------------------------------
-# beyond-paper: explicit shard_map collective schedule (see EXPERIMENTS §Perf)
-
-def make_shardmap_aggregate(cfg: RobustConfig, mesh, worker_axes=("data",)):
-    """GMoM with a hand-written collective schedule under shard_map.
-
-    Baseline GSPMD lowering of ``aggregate`` all-gathers the stacked gradient
-    over ``data`` before the batch-mean reshape.  The hand schedule instead:
-
-      1. psum the gradients *within* each batch subgroup via one
-         all-reduce over the worker axis with a batch-block mask — realized
-         as all_gather of batch-mean partial sums only (k×shard, not m×shard);
-      2. runs the trim + Weiszfeld tail on the k means locally (replicated
-         over data), dispatched through ``cfg.round_backend`` exactly like
-         ``gmom_aggregator``: the fused Pallas round kernel
-         (``repro.kernels.geomed.round``) keeps the (k, d) block
-         VMEM-resident on TPU; the jnp reference pipeline runs elsewhere
-         (and whenever the block exceeds the kernel's VMEM budget).
-         Because step 1 already produced the means, the kernel is invoked
-         with the k = m identity grouping — its membership matmul is the
-         identity and only the resident trim + Weiszfeld stages do work.
-
-    Requires the worker axis size to equal cfg.num_workers and contiguous
-    grouping.  Returns ``fn(stacked_local_grads) -> agg_grad`` to be called
-    inside shard_map (worker axis unstacked: each rank passes its own grad).
-    """
-    k = cfg.resolved_num_batches()
-    m = cfg.num_workers
-    if m % k != 0:
-        # The one-hot psum below assumes the even contiguous partition
-        # (batch_id = idx // b with a single b); an uneven grouping would
-        # silently drop workers idx >= k*b and mis-scale every mean.
-        # Uneven k (paper's m=50, k=11) is supported by the gmom/fused
-        # round path, not by this hand-scheduled collective yet.
-        raise ValueError(
-            f"make_shardmap_aggregate requires k | m (got m={m}, k={k}); "
-            "use the gmom aggregator path for uneven groupings")
-    b = m // k
-
-    def agg_local(my_grad):
-        """Runs per-rank inside shard_map; my_grad has no worker axis."""
-        axis = worker_axes[0] if len(worker_axes) == 1 else worker_axes
-        # worker index along the (possibly multi-) worker axis
-        if isinstance(axis, tuple):
-            idx = jax.lax.axis_index(axis[0]) * jax.lax.axis_size(axis[1]) \
-                + jax.lax.axis_index(axis[1])
-        else:
-            idx = jax.lax.axis_index(axis)
-        batch_id = idx // b
-
-        def leaf(g):
-            # one-hot partial contribution to each batch mean, then a single
-            # all-reduce produces all k batch means replicated on every rank.
-            onehot = (jnp.arange(k) == batch_id).astype(g.dtype) / b
-            contrib = jnp.einsum("k,...->k...", onehot, g)
-            return jax.lax.psum(contrib, axis_name=axis)
-
-        means = jax.tree.map(leaf, my_grad)
-        backend = aggregators.resolve_round_backend(
-            cfg.round_backend, num_batches=k,
-            total_dim=aggregators._total_dim(means), num_workers=k)
-        if backend != "reference":
-            from repro.core.grouping import make_grouping
-            from repro.kernels.geomed import round as round_kernel
-            return round_kernel.round_aggregate_pytree(
-                means, make_grouping(k, k),
-                trim_multiplier=cfg.trim_multiplier,
-                max_iters=cfg.gmom_max_iters, tol=cfg.gmom_tol,
-                use_pallas=(backend == "fused"),
-                interpret=(backend == "fused_interpret"))
-        weights = None
-        if cfg.trim_multiplier is not None:
-            norms = batch_mean_norms(means)
-            weights = trim_weights(norms, multiplier=cfg.trim_multiplier)
-        return geometric_median_pytree(
-            means, weights=weights, max_iters=cfg.gmom_max_iters,
-            tol=cfg.gmom_tol)
-
-    return agg_local
-
-
 def make_sharded_aggregate(cfg: RobustConfig, mesh=None, *,
                            axis: str = "model",
                            num_shards: int | None = None):
@@ -487,15 +403,13 @@ def make_sharded_aggregate(cfg: RobustConfig, mesh=None, *,
     with the stacked gradients partitioned over ``axis`` (the ZeRO-1 layout:
     each device holds every worker's slice of its param shard).
 
-    Complements :func:`make_shardmap_aggregate`, which hand-schedules the
-    *data*-axis collectives for gmom only; this one covers EVERY registered
-    rule over the *model* axis via the blocked-reduction contract
-    (``repro.core.shard_aggregation``): coordinate-wise rules run with no
-    collectives at all, norm-based rules all-reduce per-shard partial
-    squared norms.  The result is bit-identical to the ``"virtual"``-mode
-    single-device oracle on the gathered gradients — the testable form of
-    "sharded and gathered aggregation agree exactly"
-    (tests/test_shardmap_aggregate.py).
+    It covers EVERY registered rule over the *model* axis via the
+    blocked-reduction contract (``repro.core.shard_aggregation``):
+    coordinate-wise rules run with no collectives at all, norm-based rules
+    all-reduce per-shard partial squared norms.  The result is
+    bit-identical to the ``"virtual"``-mode single-device oracle on the
+    gathered gradients — the testable form of "sharded and gathered
+    aggregation agree exactly" (tests/test_shardmap_aggregate.py).
 
     Returns ``fn(stacked_local_grads, key) -> agg_grad_shard`` where each
     leaf of ``stacked_local_grads`` is the local LAST-dim slice (leading

@@ -33,14 +33,6 @@ compiles the kernel for a described v5e at the largest admitted d.
 loop and operation order exactly — it is bit-identical to the kernel in
 interpret mode (tests/test_round_kernel.py asserts exact equality).
 
-``linreg_round_*`` goes one stage further for the paper's linear-regression
-substrate (§4): the kernel receives the RAW worker batches (X, y) and the
-current iterate theta, computes every worker's full-batch gradient
-(1/n) X_j^T (X_j theta - y_j) in-kernel (two streamed passes over X), and
-feeds it straight into the same means+trim+Weiszfeld tail — the entire
-round of Algorithm 2 as one kernel.  The v5e compiler refuses it (see its
-section below).
-
 The Weiszfeld loop is an early-exiting ``lax.while_loop`` with the same
 stopping rule as the unfused jnp path (squared movement <= tol^2, capped at
 ``max_iters``).  Every pass walks the resident block one (k, TILE_D) chunk
@@ -240,13 +232,6 @@ def _finish_jnp(z, **kw):
     return y.reshape(-1)
 
 
-def _chunked(z, chunk: int):
-    """(k, d) -> (n_chunks, k, chunk), zero-padding d to a chunk multiple."""
-    z = _pad_axis(z, chunk, 1)
-    k, d_pad = z.shape
-    return z.reshape(k, d_pad // chunk, chunk).transpose(1, 0, 2)
-
-
 # ---------------------------------------------------------------------------
 # kernel 1: stacked gradients -> aggregate   (the scan trainer's hot path)
 
@@ -409,182 +394,3 @@ def round_aggregate_pytree(stacked_grads, grouping: Grouping, *,
         out.append(piece.reshape(l.shape[1:]).astype(l.dtype))
         offset += size
     return jax.tree.unflatten(treedef, out)
-
-
-# ---------------------------------------------------------------------------
-# kernel 2: raw linreg batches -> aggregate  (the whole round in-kernel)
-#
-# The v5e compiler refuses this kernel: its batched (m, n) x (m, n, tile_d)
-# dot_general has no TPU dot-dimension encoding.  Nothing on the production
-# path calls it (ROADMAP, Queue 3); its interpret-mode test keeps the tail it
-# shares with kernel 1 honest.
-
-def _linreg_round_kernel(x_ref, t_ref, theta_ref, s_ref, bsz_ref,
-                         y_ref, r_ref, z_ref, *, n_tiles, inv_n, **kw):
-    """Grid (2, n_tiles).  Phase 0 streams X to build the residual
-    R = X @ theta - y (resident, (m, n)); phase 1 streams X again to form
-    each worker's gradient tile (1/n) X^T R, contracts it with the
-    membership matrix into the resident batch means, and finishes with the
-    same trim + Weiszfeld tail as the gradient-input kernel.  X is read
-    twice and nothing else touches HBM."""
-    phase = pl.program_id(0)
-    i = pl.program_id(1)
-    x = x_ref[...]                                     # (m, n, tile_d)
-    theta_t = theta_ref[...]                           # (1, tile_d)
-    tile_d = x.shape[2]
-
-    @pl.when(phase == 0)
-    def _residual():
-        @pl.when(i == 0)
-        def _init():
-            r_ref[...] = -t_ref[...]
-        # R += X[:, :, tile] @ theta[tile]
-        part = jax.lax.dot_general(
-            x, theta_t.reshape(tile_d, 1),
-            dimension_numbers=(((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (m, n, 1)
-        r_ref[...] += part[..., 0]
-
-    @pl.when(phase == 1)
-    def _grads_means():
-        r = r_ref[...]                                 # (m, n)
-        # worker gradients for this tile: (1/n) X_j^T r_j, all j at once
-        g = jax.lax.dot_general(
-            r, x, dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * inv_n  # (m, tile_d)
-        sums = jnp.dot(s_ref[...], g,
-                       preferred_element_type=jnp.float32)
-        z_ref[i] = sums / bsz_ref[...]
-
-        @pl.when(i == n_tiles - 1)
-        def _finish():
-            _finish_in_kernel(z_ref, y_ref, **kw)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "grouping", "trim_multiplier", "max_iters", "tol", "eps", "tile_d",
-    "interpret"))
-def linreg_round_kernel(features, targets, theta, grouping: Grouping, *,
-                        trim_multiplier: float | None = 3.0,
-                        max_iters: int = 64, tol: float = 1e-8,
-                        eps: float = 1e-12, tile_d: int = 256,
-                        interpret: bool = False):
-    """One FULL failure-free round of Algorithm 2 on the linreg substrate:
-    (X (m, n, d), y (m, n), theta (d,)) -> robust aggregate gradient (d,).
-
-    The per-worker full-batch gradients (1/n) X_j^T (X_j theta - y_j) are
-    computed in-kernel — the raw batches never materialize a gradient,
-    batch-mean, or distance tensor in HBM.
-    """
-    m, n, d = features.shape
-    k = grouping.num_batches
-    x = _pad_axis(features.astype(jnp.float32), tile_d, 2)
-    d_pad = x.shape[2]
-    n_tiles = d_pad // tile_d
-    _check_vmem(k, d_pad,
-                extra_bytes=(m * n * tile_d + m * n + k * m) * 4)
-    theta_p = _pad_axis(theta.astype(jnp.float32).reshape(1, d), tile_d, 1)
-    s = jnp.asarray(assignment_matrix(grouping))
-    bsz = jnp.asarray(grouping.batch_sizes, jnp.float32).reshape(k, 1)
-
-    y, _ = pl.pallas_call(
-        functools.partial(_linreg_round_kernel, n_tiles=n_tiles,
-                          inv_n=1.0 / n, trim_multiplier=trim_multiplier,
-                          max_iters=max_iters, tol=tol, eps=eps),
-        grid=(2, n_tiles),
-        in_specs=[
-            pl.BlockSpec((m, n, tile_d), lambda p, i: (0, 0, i)),
-            pl.BlockSpec((m, n), lambda p, i: (0, 0)),
-            pl.BlockSpec((1, tile_d), lambda p, i: (0, i)),
-            pl.BlockSpec((k, m), lambda p, i: (0, 0)),
-            pl.BlockSpec((k, 1), lambda p, i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((n_tiles, 1, tile_d), lambda p, i: (0, 0, 0)),
-            pl.BlockSpec((m, n), lambda p, i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, 1, tile_d), jnp.float32),
-            jax.ShapeDtypeStruct((m, n), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((n_tiles, k, tile_d), jnp.float32)],
-        interpret=interpret,
-    )(x, targets.astype(jnp.float32), theta_p, s, bsz)
-    return y.reshape(-1)[:d]
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "grouping", "trim_multiplier", "max_iters", "tol", "eps", "tile_d"))
-def linreg_round_ref(features, targets, theta, grouping: Grouping, *,
-                     trim_multiplier: float | None = 3.0,
-                     max_iters: int = 64, tol: float = 1e-8,
-                     eps: float = 1e-12, tile_d: int = 256):
-    """jnp twin of ``linreg_round_kernel`` (same tiling and op order): the
-    bit-exact interpret-mode oracle.  Unlike the gradient-input case, the
-    residual accumulates over d-tiles (the contraction runs over the tiled
-    axis), so the mirror must replay the kernel's tile loop and partial-sum
-    chaining exactly; benchmarks use ``linreg_round_fused`` — the same
-    algorithm without the tile structure — on non-TPU backends."""
-    m, n, d = features.shape
-    k = grouping.num_batches
-    x = _pad_axis(features.astype(jnp.float32), tile_d, 2)
-    d_pad = x.shape[2]
-    n_tiles = d_pad // tile_d
-    theta_p = _pad_axis(theta.astype(jnp.float32).reshape(1, d), tile_d, 1)
-    s = jnp.asarray(assignment_matrix(grouping))
-    bsz = jnp.asarray(grouping.batch_sizes, jnp.float32).reshape(k, 1)
-    inv_n = 1.0 / n
-
-    r = -targets.astype(jnp.float32)
-    xt = [jax.lax.slice_in_dim(x, i * tile_d, (i + 1) * tile_d, axis=2)
-          for i in range(n_tiles)]
-    tt = [jax.lax.slice_in_dim(theta_p, i * tile_d, (i + 1) * tile_d, axis=1)
-          for i in range(n_tiles)]
-    for i in range(n_tiles):
-        part = jax.lax.dot_general(
-            xt[i], tt[i].reshape(tile_d, 1),
-            dimension_numbers=(((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        r = r + part[..., 0]
-    tiles = []
-    for i in range(n_tiles):
-        g = jax.lax.dot_general(
-            r, xt[i], dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * inv_n
-        tiles.append(jnp.dot(s, g, preferred_element_type=jnp.float32)
-                     / bsz)
-    y = _finish_jnp(jnp.stack(tiles), trim_multiplier=trim_multiplier,
-                    max_iters=max_iters, tol=tol, eps=eps)
-    return y[:d]
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "grouping", "trim_multiplier", "max_iters", "tol", "eps"))
-def linreg_round_fused(features, targets, theta, grouping: Grouping, *,
-                       trim_multiplier: float | None = 3.0,
-                       max_iters: int = 64, tol: float = 1e-8,
-                       eps: float = 1e-12):
-    """The fused full-round formulation for non-TPU backends: same algorithm
-    as ``linreg_round_kernel`` (analytic per-worker gradients -> membership
-    matmul means -> resident trim + Weiszfeld), written as flat jnp so XLA
-    lowers it well on CPU/GPU.  Agrees with the kernel to float tolerance
-    (reduction orders differ along d); the benchmark's "fused" entrant on
-    this container's backend."""
-    m, n, d = features.shape
-    k = grouping.num_batches
-    x = features.astype(jnp.float32)
-    s = jnp.asarray(assignment_matrix(grouping))
-    bsz = jnp.asarray(grouping.batch_sizes, jnp.float32).reshape(k, 1)
-    theta = theta.astype(jnp.float32)
-    r = jax.lax.dot_general(
-        x, theta.reshape(d, 1),
-        dimension_numbers=(((2,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[..., 0] \
-        - targets.astype(jnp.float32)                       # (m, n)
-    g = jax.lax.dot_general(
-        r, x, dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * (1.0 / n)     # (m, d)
-    z = jnp.dot(s, g, preferred_element_type=jnp.float32) / bsz
-    y = _finish_jnp(_chunked(z, TILE_D), trim_multiplier=trim_multiplier,
-                    max_iters=max_iters, tol=tol, eps=eps)
-    return y[:d]

@@ -7,9 +7,9 @@ mode (encoders), cross-attention (Seamless enc-dec), and a single-token decode
 path against a KV cache.
 
 The core score/softmax/value computation is factored into ``attention_core``
-so the Pallas flash kernel (kernels/attention) can replace it 1:1 on TPU;
-the jnp path here is also the kernel's oracle (kernels/attention/ref.py
-re-exports it).
+(and its memory-bounded twin ``attention_core_blocked``), so a fused kernel
+can replace it 1:1; tests/test_attention_core.py checks both against an
+independent float64 masked softmax.
 """
 
 from __future__ import annotations
@@ -135,12 +135,12 @@ def attention_core_blocked(q, k, v, *, causal: bool,
     """Memory-bounded attention: Python-unrolled loop over q blocks, each
     attending only to its *statically sliced* causal/window kv prefix.
 
-    This is the XLA-side realization of the Pallas flash kernel's blocking
-    (kernels/attention): the (Tq, Tk) score matrix never materializes — peak
-    intermediate is (q_block, kv_slice) per head — and, because the loop is
-    unrolled with static slices, the lowered HLO contains exactly the useful
-    dot ops (no masked-out wasted compute beyond block granularity), which
-    keeps the dry-run roofline honest.  Gradients flow through normally.
+    This is the XLA-side realization of flash attention's blocking: the
+    (Tq, Tk) score matrix never materializes — peak intermediate is
+    (q_block, kv_slice) per head — and, because the loop is unrolled with
+    static slices, the lowered HLO contains exactly the useful dot ops (no
+    masked-out wasted compute beyond block granularity), which keeps the
+    dry-run roofline honest.  Gradients flow through normally.
 
     Requires default positions (prefill layout, q_pos == kv_pos == arange).
     v's head dim may differ from q/k's, as in ``attention_core``.
@@ -181,7 +181,7 @@ def _online_softmax_attention(q, k, v, *, causal, window, q_pos, kv_block,
     q_pos: (B, Tq) absolute positions (traced OK).  Returns (B, Tq, H, hd).
 
     The static python loop over kv blocks keeps the peak intermediate at
-    (Tq, kv_block) scores per head — the XLA analogue of the Pallas kernel's
+    (Tq, kv_block) scores per head — the XLA analogue of a flash kernel's
     VMEM tiling, and exact-FLOP-visible to the dry-run roofline.
     """
     B, Tq, H, hd = q.shape
@@ -226,7 +226,7 @@ def apply_sequence_parallel(params, spec: AttentionSpec, x, *, memory=None,
                             q_block: int = 256, kv_block: int = 1024):
     """Sequence-parallel attention under shard_map (the production path).
 
-    Motivation (measured — see EXPERIMENTS.md §Perf): naive GSPMD head
+    Motivation: naive GSPMD head
     sharding collapses for GQA (num_kv_heads < |model|) and non-divisible
     head counts (minitron 24H, qwen3 40H): the partitioner reshards the
     (B, KV, G, Tq, Tk) score tensors across the contracting dims, emitting

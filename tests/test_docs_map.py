@@ -1,6 +1,7 @@
 """Tier-1 wrapper around scripts/check_docs.py: the paper↔code map
-(docs/PAPER_MAP.md), the README aggregator table, and the checked-in
-BENCH_round_kernel.json must stay consistent with the live registries."""
+(docs/PAPER_MAP.md), the README aggregator table, the checked-in pod-sweep
+record and every doc-referenced file path must stay consistent with the
+live registries and the tree."""
 
 import os
 import sys

@@ -13,6 +13,7 @@ import pytest
 
 from repro.core import geometric_median, geometric_median_pytree, \
     trim_weights, batch_mean_norms
+from repro.core.geometric_median import GRAM_PAIRS_MAX_K
 from repro.core.theory import c_alpha
 
 try:
@@ -220,12 +221,28 @@ def _gram_case(name):
     if name == "k2":
         z = rng.normal(size=(2, 9))
         return [z[:, :4], z[:, 4:]], None, 1e-6
+    # past GRAM_PAIRS_MAX_K the Gram matrix is one dot_general
+    if name == "k9":
+        z = rng.normal(size=(9, 21)) * 10
+        return [z[:, :6], z[:, 6:].reshape(9, 5, 3)], None, 1e-6
+    if name == "k16":
+        z = rng.normal(size=(16, 40)) * 10
+        return [z[:, :16], z[:, 16:].reshape(16, 4, 6)], None, 1e-6
+    if name == "k16_trimmed_sign_flip":
+        z = rng.normal(size=(16, 24)) + 3.0
+        z[15] = -10.0 * z[0]
+        return [z[:, :10], z[:, 10:]], "trim", 1e-6
+    if name == "k16_bf16_leaves":
+        z = rng.normal(size=(16, 3 * 5 + 7 + 8)) + 1.0
+        return ([z[:, :15].reshape(16, 3, 5), z[:, 15:22],
+                 z[:, 22:].reshape(16, 2, 2, 2)], "bf16", 2 ** -7)
     raise ValueError(name)
 
 
 @pytest.mark.parametrize("case", ["random", "trimmed_sign_flip",
                                   "near_coincident", "bf16_leaves", "k1",
-                                  "k2"])
+                                  "k2", "k9", "k16", "k16_trimmed_sign_flip",
+                                  "k16_bf16_leaves"])
 def test_gram_form_matches_point_form(case):
     """The pytree Weiszfeld, iterating on coefficients over X Xᵀ, lands on
     the point form's median: the f32 flat ``geometric_median`` and a
@@ -246,6 +263,11 @@ def test_gram_form_matches_point_form(case):
     info = {}
     got = geometric_median_pytree(tree, weights=w, max_iters=200, tol=move,
                                   info=info)
+    # the Gram matrix's form: fused pair reductions up to
+    # GRAM_PAIRS_MAX_K, one dot_general past it
+    jaxpr = str(jax.make_jaxpr(lambda t: geometric_median_pytree(
+        t, weights=w, max_iters=200, tol=move))(tree))
+    assert ("dot_general" in jaxpr) == (k > GRAM_PAIRS_MAX_K)
     assert [g.dtype for g in got] == [dtype] * len(tree)
     got = np.concatenate([np.asarray(g, np.float64).reshape(-1)
                           for g in got])

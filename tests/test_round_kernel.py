@@ -9,8 +9,7 @@ Three layers of guarantees:
     no silent numerical drift between backends' formulations);
 (b) semantics — the fused path agrees with the unfused jnp gmom pipeline
     (batch means -> Remark-2 trim -> pytree Weiszfeld) to float tolerance,
-    for flat and nested gradient pytrees, and the in-kernel-gradient linreg
-    variant agrees with vmap(value_and_grad) + gmom;
+    for flat and nested gradient pytrees;
 (c) system — a checked-in golden scenario trace replayed with
     round_backend="fused_interpret" reproduces the recorded trajectory.
 """
@@ -22,8 +21,6 @@ import pytest
 
 from repro.core import aggregators
 from repro.core.grouping import assignment_matrix, make_grouping
-from repro.core.robust_train import per_worker_grads
-from repro.data import regression
 from repro.kernels.geomed import round as round_kernel
 
 # the tier-1 (m, k, d) matrix: even + uneven (paper-scale m=50, k=11),
@@ -68,19 +65,6 @@ def test_round_kernel_bit_identical_across_trim(trim):
         g, grouping, interpret=True, trim_multiplier=trim, max_iters=16)
     ref = round_kernel.round_aggregate_ref(
         g, grouping, trim_multiplier=trim, max_iters=16)
-    np.testing.assert_array_equal(np.asarray(ker), np.asarray(ref))
-
-
-def test_linreg_round_kernel_bit_identical_to_ref():
-    rng = np.random.default_rng(3)
-    m, n, d, k = 12, 16, 300, 6
-    x = jnp.asarray(rng.normal(size=(m, n, d)).astype(np.float32))
-    t = jnp.asarray(rng.normal(size=(m, n)).astype(np.float32))
-    theta = jnp.asarray(rng.normal(size=(d,)).astype(np.float32))
-    grouping = make_grouping(m, k)
-    ker = round_kernel.linreg_round_kernel(x, t, theta, grouping,
-                                           interpret=True, max_iters=16)
-    ref = round_kernel.linreg_round_ref(x, t, theta, grouping, max_iters=16)
     np.testing.assert_array_equal(np.asarray(ker), np.asarray(ref))
 
 
@@ -158,24 +142,6 @@ def test_fused_gmom_matches_reference_pytree():
                                    rtol=1e-4, atol=1e-5)
 
 
-def test_linreg_round_matches_unfused_ad_path():
-    """The in-kernel gradient (raw batches in, aggregate out) equals
-    vmap(value_and_grad) -> gmom to float tolerance — the whole round."""
-    rng = np.random.default_rng(5)
-    m, n, d, k = 20, 16, 400, 10
-    x = jnp.asarray(rng.normal(size=(m, n, d)).astype(np.float32))
-    t = jnp.asarray(rng.normal(size=(m, n)).astype(np.float32))
-    theta = jnp.asarray(rng.normal(size=(d,)).astype(np.float32))
-    grads, _ = per_worker_grads(regression.squared_loss, theta, (x, t))
-    unfused = aggregators.gmom_aggregator(grads, num_batches=k,
-                                          round_backend="reference",
-                                          max_iters=16)
-    fused = round_kernel.linreg_round_ref(x, t, theta,
-                                          make_grouping(m, k), max_iters=16)
-    np.testing.assert_allclose(np.asarray(fused), np.asarray(unfused),
-                               rtol=1e-4, atol=1e-5)
-
-
 def test_uneven_batch_means_are_group_means():
     """The membership-matmul path (k does not divide m) computes exactly the
     per-group means of the permuted workers."""
@@ -202,15 +168,6 @@ def test_choose_num_batches_uneven_opt_in():
     assert choose_num_batches(20, 0) == 1
 
 
-def test_shardmap_aggregate_rejects_uneven_k():
-    """The hand-scheduled collective assumes the even contiguous partition;
-    uneven k must fail loudly, not silently drop workers."""
-    from repro.core.robust_train import RobustConfig, make_shardmap_aggregate
-    cfg = RobustConfig(num_workers=50, num_byzantine=5, num_batches=11)
-    with pytest.raises(ValueError, match=r"requires k \| m"):
-        make_shardmap_aggregate(cfg, mesh=None)
-
-
 def test_resolve_round_backend():
     resolve = aggregators.resolve_round_backend
     # explicit values pass through regardless of backend
@@ -222,6 +179,21 @@ def test_resolve_round_backend():
     if jax.default_backend() != "tpu":
         assert resolve("auto", num_batches=8, total_dim=1000) == "reference"
         assert resolve(None, num_batches=8) == "reference"
+
+
+@pytest.mark.parametrize("shape,partitioned,expected", [
+    ((50, 11, 1000), False, "fused"),                 # linreg.paper.gmom
+    ((4, 4, 468_996_096), False, "reference"),        # mistral7b.gmom cell
+    ((50, 11, 1000), True, "reference"),              # param-sharded slices
+], ids=["linreg_paper", "mistral_cell", "partitioned"])
+def test_resolve_round_backend_auto_for_tpu(shape, partitioned, expected):
+    """What ``auto`` picks for a program that runs on a TPU, whatever the
+    host: the paper's round fits the kernel's VMEM budget, a model-scale
+    gradient does not, and partitioned gradients never take the kernel."""
+    m, k, d = shape
+    assert aggregators.resolve_round_backend(
+        "auto", num_batches=k, total_dim=d, num_workers=m,
+        target_backend="tpu", partitioned=partitioned) == expected
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
-"""make_shardmap_aggregate (hand-scheduled GMoM collectives) vs the GSPMD
-``aggregate`` path on a fake 8-device CPU mesh — leaf-for-leaf equality,
-on both the reference jnp tail and the fused round-kernel backend
-(``round_backend="fused_interpret"``: the Pallas kernel in interpret mode).
+"""Aggregation on a fake 8-device CPU mesh against single-device oracles:
+the GSPMD ``aggregate`` path with the worker axis sharded over ``data``,
+and the shard-local ``make_sharded_aggregate`` body under shard_map over
+``model``.
 
 Runs in a subprocess because the virtual-device flag must be set before jax
 initializes (same pattern as test_parallel_numerics)."""
@@ -19,8 +19,7 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.core import RobustConfig, aggregate, aggregators, \\
-        make_shardmap_aggregate
+    from repro.core import RobustConfig, aggregate, aggregators
     from repro.launch.mesh import make_mesh
 
     m, k = 8, 4
@@ -42,57 +41,22 @@ SCRIPT = textwrap.dedent("""
         lambda s: aggregate(s, cfg, key=key, round_index=0),
         in_shardings=(in_shardings,))(stacked)
 
-    # --- hand-scheduled path: per-rank grads (no worker axis) via shard_map
-    agg_local = make_shardmap_aggregate(cfg, mesh)
-    specs = jax.tree.map(
-        lambda x: P(*(("data",) + (None,) * (x.ndim - 1))), stacked)
-    out_specs = jax.tree.map(lambda x: P(*((None,) * (x.ndim - 1))), stacked)
-    fn = jax.shard_map(
-        lambda s: agg_local(jax.tree.map(lambda x: x[0], s)),
-        mesh=mesh, in_specs=(specs,), out_specs=out_specs, check_vma=False)
-    handsched = jax.jit(fn)(stacked)
-
     # --- single-device oracle
     oracle = aggregators.gmom_aggregator(
         stacked, num_batches=k, num_byzantine=1,
         trim_multiplier=cfg.trim_multiplier, max_iters=cfg.gmom_max_iters,
         tol=cfg.gmom_tol)
 
-    for a, b, c in zip(jax.tree.leaves(gspmd), jax.tree.leaves(handsched),
-                       jax.tree.leaves(oracle)):
-        assert a.shape == b.shape == c.shape, (a.shape, b.shape, c.shape)
-        assert float(jnp.max(jnp.abs(a - b))) < 1e-5, "gspmd vs shard_map"
-        assert float(jnp.max(jnp.abs(b - c))) < 1e-5, "shard_map vs oracle"
-
-    # --- fused backend: the PR-3 round kernel dispatched through
-    # RobustConfig.round_backend (the trim+Weiszfeld tail runs in the Pallas
-    # interpreter on the psum'd means; identity k=m grouping in-kernel)
-    import dataclasses
-    cfg_fused = dataclasses.replace(cfg, round_backend="fused_interpret")
-    agg_fused = make_shardmap_aggregate(cfg_fused, mesh)
-    fn_fused = jax.shard_map(
-        lambda s: agg_fused(jax.tree.map(lambda x: x[0], s)),
-        mesh=mesh, in_specs=(specs,), out_specs=out_specs, check_vma=False)
-    handsched_fused = jax.jit(fn_fused)(stacked)
-
-    oracle_fused = aggregators.gmom_aggregator(
-        stacked, num_batches=k, num_byzantine=1,
-        trim_multiplier=cfg.trim_multiplier, max_iters=cfg.gmom_max_iters,
-        tol=cfg.gmom_tol, round_backend="fused_interpret")
-
-    for b, f, of in zip(jax.tree.leaves(handsched),
-                        jax.tree.leaves(handsched_fused),
-                        jax.tree.leaves(oracle_fused)):
-        assert b.shape == f.shape == of.shape, (b.shape, f.shape, of.shape)
-        assert float(jnp.max(jnp.abs(f - b))) < 1e-5, \\
-            "fused shard_map vs reference shard_map"
-        assert float(jnp.max(jnp.abs(f - of))) < 1e-5, \\
-            "fused shard_map vs fused oracle"
+    for a, c in zip(jax.tree.leaves(gspmd), jax.tree.leaves(oracle)):
+        assert a.shape == c.shape, (a.shape, c.shape)
+        assert float(jnp.max(jnp.abs(a - c))) < 1e-5, "gspmd vs oracle"
     print("OK")
 """)
 
 
 def test_shardmap_gmom_matches_gspmd_aggregate():
+    """gmom through ``aggregate`` jitted with the stacked gradients sharded
+    over an 8-device ``data`` mesh equals the single-device gmom oracle."""
     res = subprocess.run(
         [sys.executable, "-c", SCRIPT],
         capture_output=True, text=True, timeout=600,
